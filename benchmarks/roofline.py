@@ -18,9 +18,9 @@ Also hosts the CANDIDATE-PATH analytic roofline: per-stage HBM byte bills
 from ``repro.core.multistage.cascade_hbm_bytes`` (corpus read, the [B, N]
 score write, the 3x-billed naive rerank gather) combined with the Eq.-1
 madds into predicted two-term roofline seconds for the reference vs fused
-(scan_topk + rerank_kernel) serving cascade — against the peaks of the
-backend the benchmark actually runs on (``measured_peaks``: v5e datasheet
-numbers on TPU, a one-shot stream/matmul microbenchmark elsewhere).
+(scan_topk + rerank_kernel) serving cascade — against the published
+peaks of the device the benchmark runs on (``measured_peaks``, keyed by
+``device_kind``; a device without a row raises).
 ``benchmarks/run.py rerank_kernel_vs_ref`` prints this predicted ratio
 next to the measured one.
 
@@ -43,60 +43,14 @@ import argparse
 import json
 import os
 
-# TPU v5e per-chip constants (assignment-specified). These stay the
-# source of truth for the DRY-RUN analysis (it models the production TPU
-# mesh regardless of where the script runs); the candidate-path roofline
-# instead calibrates against the backend actually underneath it — see
-# measured_peaks().
+# TPU v5e per-chip constants. The DRY-RUN analysis models the production
+# TPU mesh regardless of where the script runs; the candidate-path
+# roofline reads the peaks of the device underneath it (measured_peaks).
 PEAK_FLOPS = 197e12          # bf16 FLOP/s
 HBM_BW = 819e9               # bytes/s
 LINK_BW = 50e9               # bytes/s per ICI link
 
 RESULTS = os.path.join(os.path.dirname(__file__), "results")
-
-_PEAKS: dict | None = None
-
-
-def _measure_stream_bw() -> float:
-    """Best-of-3 streaming READ bandwidth (bytes/s) of the live jax
-    backend, probed as a matvec over a 128 MB f32 matrix — the same
-    row-stream-and-reduce access pattern as the corpus scan, and the one
-    XLA actually parallelises. (A jitted elementwise copy measures
-    single-thread dispatch instead and under-reports the scan's
-    achievable bandwidth ~5x on multicore CPU hosts.)"""
-    import time as _time
-    import jax
-    import jax.numpy as jnp
-    rows, cols = 1 << 13, 1 << 12
-    m = jnp.arange(rows * cols, dtype=jnp.float32).reshape(rows, cols)
-    v = jnp.ones((cols,), jnp.float32)
-    f = jax.jit(lambda mm, vv: mm @ vv)
-    f(m, v).block_until_ready()
-    best = float("inf")
-    for _ in range(3):
-        t0 = _time.perf_counter()
-        f(m, v).block_until_ready()
-        best = min(best, _time.perf_counter() - t0)
-    return 4.0 * rows * cols / best
-
-
-def _measure_matmul_flops() -> float:
-    """Best-of-3 f32 matmul throughput (FLOP/s) of the live backend."""
-    import time as _time
-    import jax
-    import jax.numpy as jnp
-    n = 1536
-    a = jnp.full((n, n), 0.5, jnp.float32)
-    b = jnp.full((n, n), 0.25, jnp.float32)
-    f = jax.jit(lambda u, v: u @ v)
-    f(a, b).block_until_ready()
-    best = float("inf")
-    for _ in range(3):
-        t0 = _time.perf_counter()
-        f(a, b).block_until_ready()
-        best = min(best, _time.perf_counter() - t0)
-    return 2.0 * n ** 3 / best
-
 
 _H2D_BW: float | None = None
 
@@ -149,44 +103,48 @@ def tiered_overlap_roofline(scan_bytes: float, scan_flops: float,
     the emulated link rate when the A/B runs against
     ``TieredEngine(link_bw=...)`` so the prediction models the link the
     measurement actually crossed. ``t_scan_s`` likewise substitutes a
-    measured per-query scan time for the byte/flop roofline when the
-    scan is dispatch-bound (tiny per-segment calls on a CPU host)."""
-    peaks = measured_peaks()
+    measured per-query scan time for the byte/flop roofline (which needs
+    the device's published peaks, see ``measured_peaks``)."""
     bw = h2d_bw if h2d_bw else measured_h2d_bw()
-    t_scan = t_scan_s if t_scan_s else max(scan_bytes / peaks["hbm_bw"],
-                                           scan_flops / peaks["flops"])
+    if t_scan_s:
+        t_scan = t_scan_s
+    else:
+        peaks = measured_peaks()
+        t_scan = max(scan_bytes / peaks["hbm_bw"],
+                     scan_flops / peaks["flops"])
     t_xfer = (1.0 - hit_rate) * transfer_bytes / bw
     sync_s = t_scan + t_xfer
     overlap_s = max(t_scan, t_xfer)
     return {"t_scan_s": t_scan, "t_xfer_s": t_xfer,
             "sync_s": sync_s, "overlap_s": overlap_s,
-            "speedup": sync_s / max(overlap_s, 1e-30),
-            "h2d_bw": bw, "peaks": dict(peaks)}
+            "speedup": sync_s / max(overlap_s, 1e-30), "h2d_bw": bw}
 
 
-def measured_peaks(force: bool = False) -> dict:
-    """Peak FLOP/s and memory bandwidth of the backend the benchmarks
-    actually run on: the v5e datasheet numbers on TPU, a one-shot
-    microbenchmark pair (stream + matmul, cached per process) elsewhere.
+# Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"flops": PEAK_FLOPS, "int8_ops": 393e12,
+                    "hbm_bw": HBM_BW,
+                    "source": "Google Cloud documentation, 'TPU v5e'"},
+}
 
-    Predicted-vs-measured comparisons were previously computed against
-    the hardcoded TPU constants even when the measurement ran on a CPU
-    host — the predicted ratio then reflects a machine the measurement
-    never touched (BENCH_candidate_path.json showed predicted 2.98x vs
-    measured 1.23x). Calibrating both roofline terms to the live backend
-    makes the two numbers commensurable."""
-    global _PEAKS
-    if _PEAKS is not None and not force:
-        return _PEAKS
+
+class UnknownDeviceError(LookupError):
+    """The device has no row in ``DEVICE_PEAKS``: a prediction against an
+    assumed or CPU-timed peak would not be a roofline of this device."""
+
+
+def measured_peaks() -> dict:
+    """Peak bf16 FLOP/s, int8 OP/s and HBM bandwidth of the device the
+    benchmark runs on, from ``DEVICE_PEAKS`` by ``device_kind``. Raises
+    ``UnknownDeviceError`` for a device not in the table (the CPU
+    included): predictions belong beside chip measurements only."""
     import jax
-    if jax.default_backend() == "tpu":
-        _PEAKS = {"flops": PEAK_FLOPS, "hbm_bw": HBM_BW,
-                  "source": "v5e-datasheet"}
-    else:
-        _PEAKS = {"flops": _measure_matmul_flops(),
-                  "hbm_bw": _measure_stream_bw(),
-                  "source": f"measured-{jax.default_backend()}"}
-    return _PEAKS
+    kind = jax.devices()[0].device_kind
+    if kind not in DEVICE_PEAKS:
+        raise UnknownDeviceError(
+            f"no published peaks for device kind {kind!r} "
+            f"(known: {sorted(DEVICE_PEAKS)})")
+    return DEVICE_PEAKS[kind]
 
 
 def analyse(rec: dict) -> dict | None:
@@ -252,8 +210,8 @@ def candidate_path_roofline(n_docs: int, q_tokens: int, dim: int,
                             batch: int = 1,
                             bytes_per_coord: dict | None = None) -> dict:
     """Predicted roofline seconds for the serving cascade's candidate
-    path, reference vs fused policy, on the LIVE backend's measured
-    peaks (``measured_peaks``; v5e datasheet numbers on TPU).
+    path, reference vs fused policy, on the device's published peaks
+    (``measured_peaks``; raises ``UnknownDeviceError`` off the table).
 
     Bills the exact terms the fused path attacks (via
     ``repro.core.multistage.cascade_hbm_bytes``): the scan stage's

@@ -486,17 +486,22 @@ def rerank_kernel_vs_ref(table: dict, quick: bool = False):
 
     # ---- predicted-vs-measured (HBM-roofline byte model)
     try:
-        from benchmarks.roofline import candidate_path_roofline
+        from benchmarks.roofline import (UnknownDeviceError,
+                                         candidate_path_roofline)
     except ImportError:
-        from roofline import candidate_path_roofline
+        from roofline import UnknownDeviceError, candidate_path_roofline
     seg = r.store.segments[0]
-    pred = candidate_path_roofline(
-        seg.capacity, int(q.shape[1]), int(q.shape[2]), base,
-        store.dims(), store.vec_dims(), batch=int(q.shape[0]))
-    out["predicted_speedup"] = pred["speedup"]
+    try:
+        pred = candidate_path_roofline(
+            seg.capacity, int(q.shape[1]), int(q.shape[2]), base,
+            store.dims(), store.vec_dims(), batch=int(q.shape[0]))
+        predicted = f"{pred['speedup']:.2f}x"
+        out["predicted_speedup"] = pred["speedup"]
+    except UnknownDeviceError:
+        predicted = "none (no peak table for this device)"
     _emit("candidate/speedup", 0.0,
           f"measured={out['measured_speedup']:.2f}x;"
-          f"predicted={pred['speedup']:.2f}x;"
+          f"predicted={predicted};"
           f"rerank_micro={out['rerank_micro_speedup']:.2f}x")
     assert retraces == 0, (
         f"steady-state candidate-path reps retraced {retraces} times")
@@ -521,7 +526,7 @@ def _persist_candidate_path(out: dict) -> None:
     _persist_ledger("BENCH_candidate_path.json",
                     {"qps": out["qps"],
                      "measured_speedup": out["measured_speedup"],
-                     "predicted_speedup": out["predicted_speedup"],
+                     "predicted_speedup": out.get("predicted_speedup"),
                      "rerank_micro_speedup": out["rerank_micro_speedup"],
                      "rerank_impl": out["rerank_impl"],
                      "n_docs": out["n_docs"], "batch": out["batch"]})
@@ -1601,6 +1606,9 @@ def main() -> None:
                     help="run only the named suite(s) (repeatable); "
                          "composes with --quick; default is everything")
     args = ap.parse_args()
+    from repro.launch.runtime import device_line, setup_compile_cache
+    setup_compile_cache()
+    print(device_line(), flush=True)
     os.makedirs(RESULTS, exist_ok=True)
     table: dict = {}
     print("name,us_per_call,derived")

@@ -15,6 +15,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.runtime import device_line
 from repro.core import multistage as MST
 from repro.core.cropping import crop_box
 from repro.data.synthetic import (evaluate_ranking, make_benchmark,
@@ -24,6 +25,7 @@ from repro.retrieval.store import build_store
 
 
 def main():
+    print(device_line(), flush=True)
     rng = np.random.default_rng(0)
 
     # 1. preprocessing demo: empty-region cropping on a rendered page

@@ -14,6 +14,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.runtime import device_line
 from repro.core import multistage as MST
 from repro.data.synthetic import make_benchmark
 from repro.retrieval.engine import make_search_fn
@@ -29,6 +30,7 @@ def qps(fn, vectors, q, qm):
 
 
 def main():
+    print(device_line(), flush=True)
     cfg = get_config("colpali")
     print(f"{'N pages':>8s} {'1-stage QPS':>12s} {'2-stage QPS':>12s} "
           f"{'speedup':>8s} {'Eq.1 pred':>9s}")

@@ -17,6 +17,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.runtime import device_line
 from repro.core import multistage as MST
 from repro.core.matryoshka import add_truncated_stage
 from repro.data.synthetic import evaluate_ranking, make_benchmark
@@ -40,6 +41,7 @@ def bench_config(name, stages, retriever, q, qm, qrels):
 
 
 def main():
+    print(device_line(), flush=True)
     cfg = get_config("colqwen")
     bench = make_benchmark(cfg, (150, 120, 100), (30, 30, 30), seed=7)
     store = build_store(cfg, jnp.asarray(bench.pages),

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.runtime import device_line
 from repro.distributed.sharding import ShardingPolicy
 from repro.models import late_interaction as LI
 from repro.training import checkpoint as CKPT
@@ -47,6 +48,7 @@ def main():
     ap.add_argument("--small", action="store_true")
     ap.add_argument("--ckpt-dir", default="/tmp/retriever_ckpt")
     args = ap.parse_args()
+    print(device_line(), flush=True)
 
     cfg = get_config("colpali")
     if args.small:

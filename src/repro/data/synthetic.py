@@ -19,8 +19,11 @@ paper's technique depends on:
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 
@@ -111,6 +114,59 @@ def make_benchmark(cfg, n_pages_per_ds=(160, 120, 90), queries_per_ds=(40, 40, 3
     return SyntheticBenchmark(pages, token_types, np.stack(qvecs),
                               np.stack(qmasks), qrels,
                               np.asarray(ds_of_page), np.asarray(ds_of_query))
+
+
+# ---------------------------------------------------------------------------
+# device-side batches (chip-sized corpora)
+# ---------------------------------------------------------------------------
+
+def _unit(x):
+    return x / jnp.maximum(jnp.linalg.norm(x, axis=-1, keepdims=True), 1e-9)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "n_pages"))
+def page_batch(cfg, key, topics, n_pages: int, signal: float = 1.0,
+               noise: float = 0.55):
+    """``make_benchmark``'s page recipe for one batch, generated on the
+    device from ``key``: unit-noise patch vectors with a topic planted in
+    a band of 3 grid rows, led by ``cfg.n_special`` junk special tokens.
+    Returns (raw pages [n_pages, S, d] f32, topic ids [n_pages]).
+
+    A corpus of thousands of colpali pages is gigabytes of float32, so a
+    caller builds it batch by batch (``jax.random.fold_in(key, i)``) and
+    ingests each batch before generating the next."""
+    d, n_vis = cfg.out_dim, cfg.n_patches
+    grid_h = cfg.grid_h if cfg.geometry != "tiles" else cfg.n_tiles
+    k_t, k_n, k_r, k_j, k_s = jax.random.split(key, 5)
+    t = jax.random.randint(k_t, (n_pages,), 0, topics.shape[0])
+    page = noise * _unit(jax.random.normal(k_n, (n_pages, n_vis, d)))
+    r0 = jax.random.randint(k_r, (n_pages, 1), 0, max(grid_h - 3, 1))
+    row = (jnp.arange(n_vis) // (n_vis // grid_h))[None]
+    band = ((row >= r0) & (row < r0 + 3)).astype(jnp.float32)[..., None]
+    jitter = _unit(jax.random.normal(k_j, (n_pages, n_vis, d)))
+    page = _unit(page + band * signal * (topics[t][:, None, :]
+                                         + 0.15 * jitter))
+    spec = _unit(jax.random.normal(k_s, (n_pages, cfg.n_special, d)))
+    return jnp.concatenate([spec, page], axis=1), t
+
+
+def ragged_queries(topics: np.ndarray, n: int, max_tokens: int,
+                   min_tokens: int = 4, seed: int = 0) -> tuple:
+    """``n`` single queries around random topics, each with its own token
+    count in [min_tokens, max_tokens]: (queries [n, max_tokens, d] f32
+    zero-padded, masks [n, max_tokens] bool)."""
+    rng = np.random.default_rng(seed)
+    d = topics.shape[1]
+    q = np.zeros((n, max_tokens, d), np.float32)
+    m = np.zeros((n, max_tokens), bool)
+    for i in range(n):
+        k = int(rng.integers(min_tokens, max_tokens + 1))
+        qn = rng.normal(size=(k, d))
+        qn /= np.linalg.norm(qn, axis=1, keepdims=True)
+        v = topics[int(rng.integers(len(topics)))][None] + 0.35 * qn
+        q[i, :k] = v / np.linalg.norm(v, axis=1, keepdims=True)
+        m[i, :k] = True
+    return q, m
 
 
 # ---------------------------------------------------------------------------

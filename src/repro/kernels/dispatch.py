@@ -8,10 +8,12 @@ fourth ad-hoc ``impl ==`` switch with no availability probe or counter at
 all. Each re-implemented the same three decisions:
 
 - **availability** — can the Pallas impl actually execute on this
-  host/backend? Probed once (lru-cached) by tracing a tiny instance.
-- **routing** — Pallas natively on TPU; off-TPU either the interpreted
-  kernel (ops whose interpret mode is a validated serving path) or a
-  fallback impl (the fused jnp twin, or the reference).
+  host/backend? Probed once (cached): on TPU by compiling the served
+  widths, elsewhere by running a tiny interpreted instance.
+- **routing** — Pallas natively on TPU (or an error: never a silent
+  twin); off-TPU either the interpreted kernel (ops whose interpret mode
+  is a validated serving path) or a fallback impl (the fused jnp twin, or
+  the reference).
 - **observability** — trace-time dispatch counters, the OBSERVED-routing
   signal CI gates assert on (a config-derived flag could not catch a
   silent fallback).
@@ -49,11 +51,13 @@ def default_interpret() -> bool:
 class KernelOp:
     """One op family's dispatch policy.
 
-    probe         traces a tiny instance of the Pallas impl; its success
-                  defines ``available(name)`` (run at most once).
-    fallback      impl name served when the native kernel is off the
-                  table: the fused jnp twin ("jnp") or the reference
-                  ("ref").
+    probe         on TPU compiles the Pallas impl at the widths it serves
+                  (``compile_served``), elsewhere runs a tiny interpreted
+                  instance; its success defines ``available(name)`` (run
+                  at most once).
+    fallback      impl name served off-TPU when the interpreted kernel is
+                  not a serving path: the fused jnp twin ("jnp") or the
+                  reference ("ref"). Never served on TPU.
     interpret_ok  True when interpreted Pallas is a sanctioned serving
                   path off-TPU (the scan kernel's contract); False means
                   interpret mode is a correctness tool only and off-TPU
@@ -126,6 +130,27 @@ def op_names() -> tuple:
     return tuple(sorted(_REGISTRY))
 
 
+def compile_abstract(fn: Callable, shapes, sharding=None) -> str:
+    """AOT-compile ``fn`` at abstract ``shapes`` ([(shape, dtype), ...])
+    for ``sharding``'s device (the default device when None) and return
+    the compiled program's text. Nothing is allocated or run, so a probe
+    can compile corpus-sized instances."""
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+            for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def compile_served(instances: dict, family: str) -> bool:
+    """The TPU probe: compile every served instance of ``family`` from an
+    ops module's ``served_instances()`` ({case: (family, fn, shapes)}).
+    A kernel the compiler refuses raises here, so ``resolve`` raises
+    before a search fn is built around it."""
+    for fam, fn, shapes in instances.values():
+        if fam == family:
+            compile_abstract(fn, shapes)
+    return True
+
+
 def available(name: str) -> bool:
     """Whether ``name``'s Pallas impl executes on this host/backend.
 
@@ -148,19 +173,24 @@ def available(name: str) -> bool:
 def resolve(name: str, use_kernel: bool) -> tuple:
     """Pick ``(impl, interpret)`` for an op family once, at build time.
 
-    use_kernel=False is always the reference path. Otherwise: the Pallas
-    kernel natively on TPU when the probe passes; off-TPU, the interpreted
-    kernel for families whose interpret mode is a sanctioned serving path
-    (``interpret_ok``), the family's ``fallback`` impl for the rest."""
+    use_kernel=False is always the reference path. Otherwise, on TPU: the
+    native Pallas kernel, or ``RuntimeError`` when its probe fails — a
+    chip run never serves a twin in place of the kernel it asked for.
+    Off-TPU: the interpreted kernel for families whose interpret mode is
+    a sanctioned serving path (``interpret_ok``), the family's
+    ``fallback`` impl for the rest."""
     op = get(name)
     if not use_kernel:
         return "ref", True
-    interp = default_interpret()
-    if available(name):
-        if not interp:
-            return "pallas", False
-        if op.interpret_ok:
-            return "pallas", True
+    if not default_interpret():
+        if not available(name):
+            raise RuntimeError(
+                f"{name}: the Pallas kernel's probe failed on TPU; "
+                "refusing to fall back to the "
+                f"{op.fallback!r} impl")
+        return "pallas", False
+    if available(name) and op.interpret_ok:
+        return "pallas", True
     return op.fallback, True
 
 

@@ -4,19 +4,24 @@ score[b, n] = sum_q qmask[b,q] * max_j (dmask[n,j] ? <q[b,q], docs[n,j]> : -inf)
 
 **Scan kernel** — TPU adaptation of the paper's hot path (§1 Eq. 1):
 instead of materialising the [B, N, Q, D] similarity tensor in HBM
-(GPU-einsum style), the query block stays resident in VMEM while
-document-vector tiles stream HBM -> VMEM; the MXU computes
-(Q x d) @ (d x bn*bd) tiles and a running per-(query-token, doc) max lives
-in a VMEM scratch accumulator. Only the final [B, N] scores are written
-back — HBM traffic is exactly one read of the corpus per query batch
+(GPU-einsum style), a query block stays resident in VMEM while
+[bn, D, d] document tiles stream HBM -> VMEM through the Pallas grid
+pipeline (double-buffered). Only the final [B, N] scores are written
+back — HBM traffic is one read of the corpus per query batch
 (memory-roofline optimal for the scan stage).
 
-Grid: (B, N/bn, D/bd); the D axis is innermost so the accumulator carries
-across D tiles. d (=128) is exactly one MXU lane width; Q is padded to a
-multiple of 8 (sublane) and bn*bd to a multiple of 128.
+Grid: (N/bn, B/bq) with the query axis innermost, so a document tile's
+block index is constant across it and the tile is fetched once per call
+whatever B is. A query block arrives flattened to [bq*Q, d] rows: per
+document one [bq*Q, d] x [d, D] MXU matmul, a masked max over the D
+tokens, then the per-token maxima summed per query. bn is a power of two
+sized to a VMEM budget (``doc_block``); Q is padded to a multiple of 8.
+Masks and per-row outputs travel as blocks whose two minor dims are whole
+array dims or tile multiples (the TPU block rule).
 
-An int8 variant dequantises per-vector-scaled docs in VMEM before the MXU:
-HBM bytes halve vs bf16 (the memory-bound scan stage speeds up ~2x).
+An int8 variant sends the codes to the MXU unscaled and multiplies the
+per-vector scale into the similarity column (<q, c*s> == <q, c>*s): HBM
+bytes halve vs bf16.
 
 **Gather-rerank kernel** — the cascade's other memory cliff (§2.4):
 rerank stages score a SMALL per-query candidate set against the full
@@ -25,7 +30,7 @@ multi-vector rows. A jnp ``jnp.take`` gather first materialises a
 bytes) before any math runs. Here the candidate slot ids arrive via
 SCALAR PREFETCH (``pltpu.PrefetchScalarGridSpec``): the grid is
 (B, L, D/bd) and the ``docs`` BlockSpec's index map reads ``ids[b, l]``
-from SMEM to pick WHICH (1, bd, d) document tile the next HBM->VMEM DMA
+from SMEM to pick WHICH [bd, d] document tile the next HBM->VMEM DMA
 fetches — the gather IS the kernel's input stream, no gathered copy ever
 exists in HBM. The resident query block, the running per-query-token max
 accumulator (VMEM scratch, carried across D tiles), int8 dequantisation
@@ -45,199 +50,126 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e30
+HIGHEST = jax.lax.Precision.HIGHEST
+# per-block VMEM budget for a streamed document tile (the auto-pipeline
+# double-buffers it, and the scoped-VMEM default on v5e is 16 MiB)
+DOC_TILE_BYTES = 1 << 20
+Q_ROWS = 512               # query-token rows scored per MXU pass
 
 
-def _maxsim_kernel(q_ref, qm_ref, docs_ref, dm_ref, out_ref, acc_ref,
-                   *, n_d_blocks: int, scale_ref=None):
-    di = pl.program_id(2)
+def _score_docs(q, docs_ref, dm_ref, sc_ref, n_docs: int):
+    """Per-(query-token, document) MaxSim over one resident doc tile.
 
-    @pl.when(di == 0)
-    def _init():
-        acc_ref[...] = jnp.full_like(acc_ref, NEG)
+    q [R, d] f32 (R = query rows x Q tokens, flattened); docs_ref
+    [n_docs, D, d]; dm_ref / sc_ref [n_docs, D] f32 (sc_ref None for float
+    docs). Returns [R, n_docs] f32: max_j over doc j's unmasked tokens of
+    <q_r, doc_j> (NEG when every token is masked). One [R, d] x [d, D] MXU
+    matmul per document; the int8 scale multiplies the similarity column
+    (<q, c * s> == <q, c> * s) so codes go to the MXU unscaled."""
+    R = q.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (R, n_docs), 1)
 
-    q = q_ref[...].astype(jnp.float32)                  # [Q, d]
-    docs = docs_ref[...]                                # [bn, bd, d]
-    if scale_ref is not None:
-        docs = docs.astype(jnp.float32) * scale_ref[...][..., None]
-    docs = docs.astype(jnp.float32)
-    # sim[q, n, j] = <q_q, docs_{n,j}>  — contract d on the MXU
-    sim = jax.lax.dot_general(
-        q, docs, (((1,), (2,)), ((), ())),
-        preferred_element_type=jnp.float32)             # [Q, bn, bd]
-    sim = jnp.where(dm_ref[...][None, :, :] > 0, sim, NEG)
-    acc_ref[...] = jnp.maximum(acc_ref[...], jnp.max(sim, axis=2))
+    def body(j, acc):
+        doc = docs_ref[j].astype(jnp.float32)                   # [D, d]
+        sim = jax.lax.dot_general(
+            q, doc, (((1,), (1,)), ((), ())), precision=HIGHEST,
+            preferred_element_type=jnp.float32)                 # [R, D]
+        if sc_ref is not None:
+            sim = sim * sc_ref[pl.ds(j, 1), :]
+        sim = jnp.where(dm_ref[pl.ds(j, 1), :] > 0, sim, NEG)
+        return jnp.where(lane == j, jnp.max(sim, axis=1, keepdims=True), acc)
 
-    @pl.when(di == n_d_blocks - 1)
-    def _finish():
-        best = acc_ref[...]                             # [Q, bn]
-        best = jnp.where(qm_ref[...][:, None] > 0,
-                         jnp.maximum(best, NEG / 2), 0.0)
-        # docs that are fully masked contribute NEG; clamp never triggers for
-        # real docs. Padding docs produce garbage scores, masked by caller.
-        out_ref[...] = jnp.sum(best, axis=0)
+    return jax.lax.fori_loop(0, n_docs, body,
+                             jnp.full((R, n_docs), NEG, jnp.float32))
+
+
+def _sum_tokens(best, qm, n_q: int):
+    """[R, n] per-token maxima + [R, 1] query-token mask -> [R / n_q, n]
+    scores. Masked tokens add 0; a fully-masked document's NEG is clamped
+    to NEG/2 per token (padding docs produce garbage, masked by caller)."""
+    best = jnp.where(qm > 0, jnp.maximum(best, NEG / 2), 0.0)
+    return jnp.sum(best.reshape(best.shape[0] // n_q, n_q, best.shape[1]),
+                   axis=1)
+
+
+def _maxsim_kernel(q_ref, qm_ref, docs_ref, dm_ref, *rest, n_q: int,
+                   n_docs: int):
+    sc_ref = rest[0] if len(rest) == 2 else None
+    out_ref = rest[-1]
+    best = _score_docs(q_ref[...].astype(jnp.float32), docs_ref, dm_ref,
+                       sc_ref, n_docs)
+    out_ref[...] = _sum_tokens(best, qm_ref[...], n_q)
+
+
+def doc_block(D: int, d: int, itemsize: int, n: int,
+              budget: int = DOC_TILE_BYTES) -> int:
+    """Documents per streamed tile: a power of two (store capacities are
+    powers of two, so no corpus-sized pad copy) of at least 8 (sublane
+    tile of the [n, D] mask block) whose [n, D, d] tile fits ``budget``."""
+    per_doc = max(D, 8) * d * itemsize
+    bn = 8
+    while bn * 2 * per_doc <= budget and bn * 2 <= max(n, 8):
+        bn *= 2
+    return bn
+
+
+def query_block(B: int, Q: int) -> int:
+    """Query rows per grid step: all of them up to ``Q_ROWS`` token rows,
+    else a multiple of 8 (the output block's sublane tile)."""
+    if B * Q <= Q_ROWS:
+        return B
+    return max(8, (Q_ROWS // Q) // 8 * 8)
 
 
 def maxsim_pallas(q: jax.Array, q_mask: jax.Array, docs: jax.Array,
                   doc_mask: jax.Array, *, block_n: int = 8,
-                  block_d: int = 0, scales: jax.Array | None = None,
+                  scales: jax.Array | None = None,
                   interpret: bool = True) -> jax.Array:
     """q [B,Q,d] f32/bf16; q_mask [B,Q] f32; docs [N,D,d] (f32/bf16/int8);
     doc_mask [N,D] f32; scales [N,D] f32 when docs are int8. -> [B,N] f32.
 
-    Shapes must be pre-padded: N % block_n == 0, D % block_d == 0.
+    Shapes must be pre-padded: Q % 8 == 0, N % block_n == 0,
+    B % query_block(B, Q) == 0. Grid is (N / block_n, query blocks) with
+    the query axis innermost: a doc tile's block index does not change
+    across it, so the pipeline fetches each tile once and the corpus is
+    read once per call whatever B is. Query blocks arrive flattened to
+    [bq*Q, d] rows so one MXU matmul scores every query token against a
+    document; per-row outputs are written as [bq, block_n] blocks of an
+    [N / block_n, B, block_n] array (lane-dense, no squeezed 2-D block).
     """
     B, Q, d = q.shape
     N, D, dd = docs.shape
-    assert d == dd
-    if block_d <= 0:
-        block_d = D
-    assert N % block_n == 0 and D % block_d == 0, (N, D, block_n, block_d)
-    n_d_blocks = D // block_d
-
+    assert d == dd and Q % 8 == 0, (q.shape, docs.shape)
+    bq = query_block(B, Q)
+    assert N % block_n == 0 and B % bq == 0, (N, block_n, B, bq)
+    nb = N // block_n
     in_specs = [
-        pl.BlockSpec((None, Q, d), lambda b, n, j: (b, 0, 0)),       # q
-        pl.BlockSpec((None, Q), lambda b, n, j: (b, 0)),             # q_mask
-        pl.BlockSpec((block_n, block_d, d), lambda b, n, j: (n, j, 0)),  # docs
-        pl.BlockSpec((block_n, block_d), lambda b, n, j: (n, j)),    # doc_mask
+        pl.BlockSpec((bq * Q, d), lambda n, b: (b, 0)),            # q rows
+        pl.BlockSpec((bq * Q, 1), lambda n, b: (b, 0)),            # q_mask
+        pl.BlockSpec((block_n, D, d), lambda n, b: (n, 0, 0)),     # docs
+        pl.BlockSpec((block_n, D), lambda n, b: (n, 0)),           # doc_mask
     ]
-    args = [q, q_mask.astype(jnp.float32), docs, doc_mask.astype(jnp.float32)]
-    kernel = functools.partial(_maxsim_kernel, n_d_blocks=n_d_blocks)
+    args = [q.reshape(B * Q, d), q_mask.astype(jnp.float32).reshape(B * Q, 1),
+            docs, doc_mask.astype(jnp.float32)]
     if scales is not None:
-        in_specs.append(
-            pl.BlockSpec((block_n, block_d), lambda b, n, j: (n, j)))
+        in_specs.append(pl.BlockSpec((block_n, D), lambda n, b: (n, 0)))
         args.append(scales.astype(jnp.float32))
-
-        def kernel(q_ref, qm_ref, docs_ref, dm_ref, s_ref, out_ref, acc_ref):
-            _maxsim_kernel(q_ref, qm_ref, docs_ref, dm_ref, out_ref, acc_ref,
-                           n_d_blocks=n_d_blocks, scale_ref=s_ref)
-
-    return pl.pallas_call(
-        kernel,
-        grid=(B, N // block_n, n_d_blocks),
+    out = pl.pallas_call(
+        functools.partial(_maxsim_kernel, n_q=Q, n_docs=block_n),
+        grid=(nb, B // bq),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, block_n), lambda b, n, j: (b, n)),
-        out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((Q, block_n), jnp.float32)],
+        out_specs=pl.BlockSpec((None, bq, block_n), lambda n, b: (n, b, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb, B, block_n), jnp.float32),
         interpret=interpret,
     )(*args)
+    return jnp.moveaxis(out, 0, 1).reshape(B, N)
 
 
-def _maxsim_db_kernel(q_ref, qm_ref, docs_hbm, dm_hbm, out_ref, docs_buf,
-                      dm_buf, sem, *, chunk: int, n_chunks: int,
-                      scales_hbm=None, scale_buf=None):
-    """Manually double-buffered scan step: chunk i+1's HBM -> VMEM DMA is
-    in flight while chunk i runs on the MXU (same per-chunk math as
-    ``_maxsim_kernel`` over a [chunk, D, d] tile). Grid is (n_chunks,);
-    docs/mask/scales stay in HBM (``pl.ANY`` BlockSpecs) and stream
-    through a 2-slot VMEM scratch + DMA-semaphore pair — the kernel-level
-    twin of ``retrieval.tiering``'s segment-granularity prefetch."""
-    i = pl.program_id(0)
-
-    def _start(slot, ci):
-        base = ci * chunk
-        pltpu.make_async_copy(docs_hbm.at[pl.ds(base, chunk)],
-                              docs_buf.at[slot], sem.at[slot, 0]).start()
-        pltpu.make_async_copy(dm_hbm.at[pl.ds(base, chunk)],
-                              dm_buf.at[slot], sem.at[slot, 1]).start()
-        if scales_hbm is not None:
-            pltpu.make_async_copy(scales_hbm.at[pl.ds(base, chunk)],
-                                  scale_buf.at[slot],
-                                  sem.at[slot, 2]).start()
-
-    @pl.when(i == 0)
-    def _warmup():                 # first chunk has nothing to hide under
-        _start(0, 0)
-
-    @pl.when(i + 1 < n_chunks)
-    def _prefetch():               # the overlap: next fetch under this MXU
-        _start((i + 1) % 2, i + 1)
-
-    slot = i % 2
-    base = i * chunk
-    pltpu.make_async_copy(docs_hbm.at[pl.ds(base, chunk)],
-                          docs_buf.at[slot], sem.at[slot, 0]).wait()
-    pltpu.make_async_copy(dm_hbm.at[pl.ds(base, chunk)],
-                          dm_buf.at[slot], sem.at[slot, 1]).wait()
-    if scales_hbm is not None:
-        pltpu.make_async_copy(scales_hbm.at[pl.ds(base, chunk)],
-                              scale_buf.at[slot], sem.at[slot, 2]).wait()
-
-    q = q_ref[...].astype(jnp.float32)                  # [B, Q, d]
-    docs = docs_buf[slot]                               # [chunk, D, d]
-    if scale_buf is not None:
-        docs = docs.astype(jnp.float32) * scale_buf[slot][..., None]
-    docs = docs.astype(jnp.float32)
-    # sim[b, q, n, j] = <q_bq, docs_nj> — contract d on the MXU
-    sim = jax.lax.dot_general(
-        q, docs, (((2,), (2,)), ((), ())),
-        preferred_element_type=jnp.float32)             # [B, Q, chunk, D]
-    sim = jnp.where(dm_buf[slot][None, None, :, :] > 0, sim, NEG)
-    best = jnp.max(sim, axis=3)                         # [B, Q, chunk]
-    best = jnp.where(qm_ref[...][:, :, None] > 0,
-                     jnp.maximum(best, NEG / 2), 0.0)
-    out_ref[...] = jnp.sum(best, axis=1)                # [B, chunk]
-
-
-def maxsim_pallas_db(q: jax.Array, q_mask: jax.Array, docs: jax.Array,
-                     doc_mask: jax.Array, *, chunk: int,
-                     scales: jax.Array | None = None,
-                     interpret: bool = False) -> jax.Array:
-    """Double-buffered streaming scan: q [B,Q,d], docs [N,D,d]
-    (f32/bf16/int8 with ``scales`` [N,D]), doc_mask [N,D] -> [B,N] f32.
-
-    N must be a chunk multiple (callers pad with fully-masked rows). The
-    query block is VMEM-resident for the whole grid; each grid step DMAs
-    one [chunk, D, d] corpus tile into the idle half of a 2-slot scratch
-    while the MXU scores the other half, so steady-state wall clock is
-    max(T_fetch, T_compute) per chunk instead of their sum. Semantics are
-    allclose-level with ``maxsim_pallas`` over the same rows (identical
-    per-element math; reduction grouping differs), and the jnp reference
-    stays the bitwise contract — this path only dispatches natively on
-    TPU (``ops.maxsim_scores_chunked`` keeps interpret-mode hosts on the
-    automatic-pipeline kernel)."""
-    B, Q, d = q.shape
-    N, D, dd = docs.shape
-    assert d == dd and N % chunk == 0, (q.shape, docs.shape, chunk)
-    n_chunks = N // chunk
-    dm = doc_mask.astype(jnp.float32)
-    in_specs = [
-        pl.BlockSpec((B, Q, d), lambda i: (0, 0, 0)),    # q: resident
-        pl.BlockSpec((B, Q), lambda i: (0, 0)),          # q_mask
-        pl.BlockSpec(memory_space=pl.ANY),               # docs stay in HBM
-        pl.BlockSpec(memory_space=pl.ANY),               # doc_mask
-    ]
-    args = [q, q_mask.astype(jnp.float32), docs, dm]
-    scratch = [pltpu.VMEM((2, chunk, D, d), docs.dtype),
-               pltpu.VMEM((2, chunk, D), jnp.float32),
-               pltpu.SemaphoreType.DMA((2, 3))]
-    kernel = functools.partial(_maxsim_db_kernel, chunk=chunk,
-                               n_chunks=n_chunks)
-    if scales is not None:
-        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-        args.append(scales.astype(jnp.float32))
-        scratch.insert(2, pltpu.VMEM((2, chunk, D), jnp.float32))
-
-        def kernel(q_ref, qm_ref, docs_hbm, dm_hbm, s_hbm, out_ref,
-                   docs_buf, dm_buf, scale_buf, sem):
-            _maxsim_db_kernel(q_ref, qm_ref, docs_hbm, dm_hbm, out_ref,
-                              docs_buf, dm_buf, sem, chunk=chunk,
-                              n_chunks=n_chunks, scales_hbm=s_hbm,
-                              scale_buf=scale_buf)
-
-    return pl.pallas_call(
-        kernel,
-        grid=(n_chunks,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((B, chunk), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(*args)
-
-
-def _rerank_kernel(ids_ref, q_ref, qm_ref, docs_ref, dm_ref, out_ref,
-                   acc_ref, *, n_d_blocks: int, scale_ref=None):
+def _rerank_kernel(ids_ref, q_ref, qm_ref, docs_ref, dm_ref, *rest,
+                   n_d_blocks: int):
     del ids_ref            # consumed by the BlockSpec index maps, not here
+    sc_ref = rest[0] if len(rest) == 3 else None
+    out_ref, acc_ref = rest[-2:]
     di = pl.program_id(2)
 
     @pl.when(di == 0)
@@ -245,26 +177,24 @@ def _rerank_kernel(ids_ref, q_ref, qm_ref, docs_ref, dm_ref, out_ref,
         acc_ref[...] = jnp.full_like(acc_ref, NEG)
 
     q = q_ref[...].astype(jnp.float32)                  # [Q, d]
-    doc = docs_ref[...][0]                              # [bd, d]
-    if scale_ref is not None:
-        doc = doc.astype(jnp.float32) * scale_ref[...][0][:, None]
-    doc = doc.astype(jnp.float32)
+    doc = docs_ref[...].astype(jnp.float32)             # [bd, d]
     # sim[q, j] = <q_q, doc_j> — contract d on the MXU
     sim = jax.lax.dot_general(
-        q, doc, (((1,), (1,)), ((), ())),
+        q, doc, (((1,), (1,)), ((), ())), precision=HIGHEST,
         preferred_element_type=jnp.float32)             # [Q, bd]
-    sim = jnp.where(dm_ref[...][0][None, :] > 0, sim, NEG)
+    if sc_ref is not None:
+        sim = sim * sc_ref[...]                         # [1, bd] scales
+    sim = jnp.where(dm_ref[...] > 0, sim, NEG)
     acc_ref[...] = jnp.maximum(acc_ref[...],
                                jnp.max(sim, axis=1, keepdims=True))
 
     @pl.when(di == n_d_blocks - 1)
     def _finish():
-        best = acc_ref[...][:, 0]                       # [Q]
         # NO NEG/2 clamp (unlike the scan kernel): the rerank contract is
         # ``core.maxsim.maxsim_scan``, which sums the raw per-token max —
         # a fully-masked candidate scores Qv*NEG on every rerank impl
-        best = jnp.where(qm_ref[...] > 0, best, 0.0)
-        out_ref[...] = jnp.sum(best)[None]
+        best = jnp.where(qm_ref[...] > 0, acc_ref[...], 0.0)   # [Q, 1]
+        out_ref[...] = jnp.sum(best, axis=0, keepdims=True)
 
 
 def maxsim_rerank_pallas(rows: jax.Array, q: jax.Array, q_mask: jax.Array,
@@ -283,9 +213,12 @@ def maxsim_rerank_pallas(rows: jax.Array, q: jax.Array, q_mask: jax.Array,
     (0, j) — never a corpus-sized ones array); scales [N, D] f32 when
     docs are int8. -> scores [B, L] f32.
 
-    Shapes must be pre-padded: D % block_d == 0. Grid is (B, L, D/bd) with
-    the D axis innermost so the per-query-token running max carries across
-    a candidate's D tiles in VMEM scratch.
+    Shapes must be pre-padded: D % block_d == 0 (block_d a multiple of
+    128, or D). Grid is (B, L, D/bd) with the D axis innermost so the
+    per-query-token running max carries across a candidate's D tiles in
+    VMEM scratch. Per-token rows (masks, scales) travel as [*, 1, D] and
+    the query mask as [B, Q, 1], so every block's two minor dims are
+    whole array dims or lane/sublane-tile multiples.
     """
     B, Q, d = q.shape
     N, D, dd = docs.shape
@@ -296,40 +229,36 @@ def maxsim_rerank_pallas(rows: jax.Array, q: jax.Array, q_mask: jax.Array,
     assert D % block_d == 0, (D, block_d)
     n_d_blocks = D // block_d
     if doc_mask.shape[0] == 1:               # broadcast (mask-less store)
-        dm_index = lambda b, l, j, ids: (0, j)            # noqa: E731
+        dm_index = lambda b, l, j, ids: (0, 0, j)         # noqa: E731
     else:
-        dm_index = lambda b, l, j, ids: (ids[b, l], j)    # noqa: E731
+        dm_index = lambda b, l, j, ids: (ids[b, l], 0, j)  # noqa: E731
 
     in_specs = [
         pl.BlockSpec((None, Q, d), lambda b, l, j, ids: (b, 0, 0)),     # q
-        pl.BlockSpec((None, Q), lambda b, l, j, ids: (b, 0)),           # qm
-        pl.BlockSpec((1, block_d, d),
+        pl.BlockSpec((None, Q, 1), lambda b, l, j, ids: (b, 0, 0)),     # qm
+        pl.BlockSpec((None, block_d, d),
                      lambda b, l, j, ids: (ids[b, l], j, 0)),           # docs
-        pl.BlockSpec((1, block_d), dm_index),                           # dm
+        pl.BlockSpec((None, 1, block_d), dm_index),                     # dm
     ]
-    args = [q, q_mask.astype(jnp.float32), docs, doc_mask.astype(jnp.float32)]
-    kernel = functools.partial(_rerank_kernel, n_d_blocks=n_d_blocks)
+    args = [q, q_mask.astype(jnp.float32)[..., None], docs,
+            doc_mask.astype(jnp.float32)[:, None, :]]
     if scales is not None:
-        in_specs.append(
-            pl.BlockSpec((1, block_d), lambda b, l, j, ids: (ids[b, l], j)))
-        args.append(scales.astype(jnp.float32))
-
-        def kernel(ids_ref, q_ref, qm_ref, docs_ref, dm_ref, s_ref,
-                   out_ref, acc_ref):
-            _rerank_kernel(ids_ref, q_ref, qm_ref, docs_ref, dm_ref,
-                           out_ref, acc_ref, n_d_blocks=n_d_blocks,
-                           scale_ref=s_ref)
+        in_specs.append(pl.BlockSpec(
+            (None, 1, block_d), lambda b, l, j, ids: (ids[b, l], 0, j)))
+        args.append(scales.astype(jnp.float32)[:, None, :])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, L, n_d_blocks),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, 1), lambda b, l, j, ids: (b, l)),
+        out_specs=pl.BlockSpec((None, None, 1, 1),
+                               lambda b, l, j, ids: (b, l, 0, 0)),
         scratch_shapes=[pltpu.VMEM((Q, 1), jnp.float32)],
     )
-    return pl.pallas_call(
-        kernel,
+    out = pl.pallas_call(
+        functools.partial(_rerank_kernel, n_d_blocks=n_d_blocks),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, L), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, L, 1, 1), jnp.float32),
         interpret=interpret,
     )(rows.astype(jnp.int32), *args)
+    return out.reshape(B, L)

@@ -38,8 +38,8 @@ import jax.numpy as jnp
 
 from repro.kernels import dispatch as DSP
 from repro.kernels.dispatch import default_interpret
-from repro.kernels.maxsim.maxsim import (maxsim_pallas, maxsim_pallas_db,
-                                         maxsim_rerank_pallas)
+from repro.kernels.maxsim.maxsim import (doc_block, maxsim_pallas,
+                                         maxsim_rerank_pallas, query_block)
 from repro.kernels.maxsim.ref import NEG, maxsim_ref
 
 
@@ -53,21 +53,22 @@ def _pad_to(x: jax.Array, axis: int, mult: int, value=0):
     return jnp.pad(x, widths, constant_values=value)
 
 
-@functools.partial(jax.jit, static_argnames=("impl", "block_n", "block_d",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("impl", "block_n", "interpret"))
 def maxsim_scores(q: jax.Array, docs: jax.Array,
                   q_mask: jax.Array | None = None,
                   doc_mask: jax.Array | None = None,
                   scales: jax.Array | None = None,
                   doc_valid: jax.Array | None = None,
-                  *, impl: str = "pallas", block_n: int = 8,
-                  block_d: int = 0, interpret: bool = True) -> jax.Array:
+                  *, impl: str = "pallas", block_n: int = 0,
+                  interpret: bool = True) -> jax.Array:
     """q [B,Q,d], docs [N,D,d] -> scores [B,N] (f32).
 
-    ``doc_valid`` [N] bool marks live documents in a capacity-padded store;
-    dead slots score NEG so they can never enter a top-k on merit. The mask
-    is applied to the kernel OUTPUT — the kernel still streams the full
-    padded corpus (shape stability is what makes mutation retrace-free).
+    ``block_n`` documents stream per kernel grid step (0 = sized by
+    ``maxsim.doc_block`` to the VMEM tile budget). ``doc_valid`` [N] bool
+    marks live documents in a capacity-padded store; dead slots score NEG
+    so they can never enter a top-k on merit. The mask is applied to the
+    kernel OUTPUT — the kernel still streams the full padded corpus
+    (shape stability is what makes mutation retrace-free).
     """
     B, Q, d = q.shape
     N, D, _ = docs.shape
@@ -85,33 +86,34 @@ def maxsim_scores(q: jax.Array, docs: jax.Array,
             out = jnp.where(doc_valid[None, :], out, NEG)
         return out
 
-    # pad Q to sublane multiple, N to block_n, D to block_d (or lane mult)
+    # pad Q to the sublane tile, B to the query block, N to block_n
+    bn = block_n or doc_block(D, d, docs.dtype.itemsize, N)
     qp = _pad_to(q, 1, 8)
     qmp = _pad_to(q_mask, 1, 8)
-    bd = block_d if block_d > 0 else min(D, 256)
-    docs_p = _pad_to(_pad_to(docs, 0, block_n), 1, bd)
-    dm_p = _pad_to(_pad_to(doc_mask, 0, block_n), 1, bd)
-    sc_p = None
-    if scales is not None:
-        sc_p = _pad_to(_pad_to(scales, 0, block_n), 1, bd)
-    out = maxsim_pallas(qp, qmp, docs_p, dm_p, block_n=block_n,
-                        block_d=bd, scales=sc_p, interpret=interpret)
-    out = out[:, :N]
+    bq = query_block(B, qp.shape[1])
+    qp, qmp = _pad_to(qp, 0, bq), _pad_to(qmp, 0, bq)
+    docs_p = _pad_to(docs, 0, bn)
+    dm_p = _pad_to(doc_mask, 0, bn)
+    sc_p = None if scales is None else _pad_to(scales, 0, bn)
+    out = maxsim_pallas(qp, qmp, docs_p, dm_p, block_n=bn, scales=sc_p,
+                        interpret=interpret)[:B, :N]
     if doc_valid is not None:
         out = jnp.where(doc_valid[None, :], out, NEG)
     return out
 
 
 def _probe_scan() -> bool:
-    """Trace a tiny scan-kernel instance; success defines availability.
+    """The ``maxsim_scan`` probe; success defines availability.
 
-    Registered as the ``maxsim_scan`` probe — the serving engine resolves
-    through ``dispatch.resolve`` once per search-fn build and falls back
-    to the jnp reference when this fails (e.g. a backend without Pallas
-    support and without a working interpreter)."""
+    On TPU it compiles the served scan instances (``served_instances``).
+    Elsewhere it runs a tiny interpreted instance, and the serving engine
+    falls back to the jnp reference when that fails (a backend without a
+    working Pallas interpreter)."""
+    if not default_interpret():
+        return DSP.compile_served(served_instances(), "maxsim_scan")
     q = jnp.zeros((1, 8, 128), jnp.float32)
     docs = jnp.zeros((8, 8, 128), jnp.float32)
-    out = maxsim_scores(q, docs, impl="pallas", block_n=8, block_d=8,
+    out = maxsim_scores(q, docs, impl="pallas", block_n=8,
                         interpret=default_interpret())
     jax.block_until_ready(out)
     return True
@@ -122,70 +124,31 @@ def pallas_available() -> bool:
     return DSP.available("maxsim_scan")
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def maxsim_scores_pipelined(q: jax.Array, docs: jax.Array,
-                            q_mask: jax.Array | None = None,
-                            doc_mask: jax.Array | None = None,
-                            scales: jax.Array | None = None,
-                            doc_valid: jax.Array | None = None,
-                            *, chunk: int,
-                            interpret: bool = False) -> jax.Array:
-    """The double-buffered streaming scan (``maxsim_pallas_db``): one
-    kernel launch whose grid steps DMA chunk i+1 HBM -> VMEM while chunk i
-    runs on the MXU — the chunked scan's wall clock drops from
-    sum(T_fetch + T_compute) to ~max per chunk. Padding/validity handling
-    mirrors ``maxsim_scores_chunked``; recorded as the "pallas_db" impl so
-    the dispatch ledger distinguishes it from the auto-pipelined kernel."""
-    B = q.shape[0]
-    N, D, _ = docs.shape
-    if q_mask is None:
-        q_mask = jnp.ones((B, q.shape[1]), jnp.float32)
-    if doc_mask is None:
-        doc_mask = jnp.ones((N, D), jnp.float32)
-    DSP.record("maxsim_scan", "pallas_db")
-    chunk = min(chunk, N) if chunk > 0 else N
-    docs_p = _pad_to(docs, 0, chunk)
-    dm_p = _pad_to(doc_mask.astype(jnp.float32), 0, chunk)
-    sc_p = None if scales is None else _pad_to(scales, 0, chunk)
-    out = maxsim_pallas_db(q, q_mask.astype(jnp.float32), docs_p, dm_p,
-                           chunk=chunk, scales=sc_p,
-                           interpret=interpret)[:, :N]
-    if doc_valid is not None:
-        out = jnp.where(doc_valid[None, :], out, NEG)
-    return out
-
-
 def maxsim_scores_chunked(q: jax.Array, docs: jax.Array,
                           q_mask: jax.Array | None = None,
                           doc_mask: jax.Array | None = None,
                           scales: jax.Array | None = None,
                           doc_valid: jax.Array | None = None,
                           *, chunk: int, impl: str = "pallas",
-                          block_n: int = 8, block_d: int = 0,
+                          block_n: int = 0,
                           interpret: bool = True) -> jax.Array:
-    """Streaming corpus scan: score ``chunk`` documents per kernel launch.
-
-    Bounds the per-step intermediate (for impl="ref", the [B, chunk, Q, D]
-    similarity block) regardless of corpus size N. N is padded up to a
+    """Streaming corpus scan. The reference scores ``chunk`` documents per
+    ``lax.map`` step, bounding its [B, chunk, Q, D] similarity block
+    regardless of corpus size N; the kernel impls ignore ``chunk`` (their
+    grid already streams VMEM-sized tiles). N is padded up to a
     chunk multiple with fully-masked documents and the padding stripped
     from the returned [B, N] scores. chunk <= 0 means unchunked.
     ``doc_valid`` [N] bool NEGs dead capacity-padding slots (applied once on
     the assembled [B, N] output, not per chunk).
     """
     N, D, _ = docs.shape
-    if chunk <= 0 or chunk >= N:
+    if impl != "ref" or chunk <= 0 or chunk >= N:
+        # the kernel streams [block_n, D, d] tiles through VMEM itself, so
+        # its intermediate is bounded whatever N is: chunking it would only
+        # add launches
         return maxsim_scores(q, docs, q_mask, doc_mask, scales, doc_valid,
-                             impl=impl, block_n=block_n, block_d=block_d,
+                             impl=impl, block_n=block_n,
                              interpret=interpret)
-    if impl == "pallas" and not interpret:
-        # native TPU: the chunked kernel scan IS the double-buffered
-        # pipeline — chunk i+1's HBM -> VMEM DMA hides under chunk i's
-        # MXU time. Interpret-mode hosts keep the auto-pipelined kernel
-        # below (same jnp-contract semantics, no manual-DMA emulation on
-        # the serving path).
-        return maxsim_scores_pipelined(q, docs, q_mask, doc_mask, scales,
-                                       doc_valid, chunk=chunk,
-                                       interpret=False)
     if doc_mask is None:
         doc_mask = jnp.ones((N, D), jnp.float32)
     docs = _pad_to(docs, 0, chunk)
@@ -196,7 +159,7 @@ def maxsim_scores_chunked(q: jax.Array, docs: jax.Array,
     db = docs.reshape(n_blocks, chunk, *docs.shape[1:])
     mb = doc_mask.reshape(n_blocks, chunk, D)
     call = functools.partial(maxsim_scores, impl=impl, block_n=block_n,
-                             block_d=block_d, interpret=interpret)
+                             interpret=interpret)
     if scales is None:
         out = jax.lax.map(lambda a: call(q, a[0], q_mask, a[1]), (db, mb))
     else:
@@ -336,10 +299,13 @@ def maxsim_rerank(q: jax.Array, docs: jax.Array, rows: jax.Array,
 
 
 def _probe_rerank() -> bool:
-    """Trace a tiny gather-rerank kernel instance (the ``maxsim_rerank``
-    probe; the registry snapshots the dispatch counters around it, so an
-    availability check can never satisfy the CI gate's "the cascade
-    really routed through the fused path" signal)."""
+    """The ``maxsim_rerank`` probe: the served rerank instances compiled
+    on TPU, a tiny interpreted instance elsewhere (the registry snapshots
+    the dispatch counters around it, so an availability check can never
+    satisfy the CI gate's "the cascade really routed through the fused
+    path" signal)."""
+    if not default_interpret():
+        return DSP.compile_served(served_instances(), "maxsim_rerank")
     q = jnp.zeros((1, 8, 128), jnp.float32)
     docs = jnp.zeros((8, 8, 128), jnp.float32)
     rows = jnp.zeros((1, 2), jnp.int32)
@@ -390,16 +356,21 @@ def centroid_scores(q: jax.Array, centroids: jax.Array,
         return qs @ centroids.astype(jnp.float32).T
     qp = _pad_to(q, 1, 8)
     qmp = _pad_to(q_mask, 1, 8)
+    bq = query_block(B, qp.shape[1])
+    qp, qmp = _pad_to(qp, 0, bq), _pad_to(qmp, 0, bq)
     docs_p = _pad_to(centroids[:, None, :].astype(jnp.float32), 0, 8)
     dm_p = jnp.ones((docs_p.shape[0], 1), jnp.float32)
-    out = maxsim_pallas(qp, qmp, docs_p, dm_p, block_n=8, block_d=1,
+    out = maxsim_pallas(qp, qmp, docs_p, dm_p, block_n=8,
                         interpret=interpret)
-    return out[:, :K]
+    return out[:B, :K]
 
 
 def _probe_route() -> bool:
-    """Trace a tiny centroid-routing kernel instance (the ``ivf_route``
-    probe; counter snapshot/restore handled by the registry)."""
+    """The ``ivf_route`` probe: the served routing instance compiled on
+    TPU, a tiny interpreted instance elsewhere (counter snapshot/restore
+    handled by the registry)."""
+    if not default_interpret():
+        return DSP.compile_served(served_instances(), "ivf_route")
     q = jnp.zeros((1, 8, 128), jnp.float32)
     cents = jnp.zeros((8, 128), jnp.float32)
     out = centroid_scores(q, cents, impl="pallas",
@@ -430,8 +401,7 @@ def maxsim_topk_chunked(q: jax.Array, docs: jax.Array,
                         scales: jax.Array | None = None,
                         doc_valid: jax.Array | None = None,
                         *, k: int, chunk: int, impl: str = "pallas",
-                        block_n: int = 8, block_d: int = 0,
-                        interpret: bool = True) -> tuple:
+                        block_n: int = 0, interpret: bool = True) -> tuple:
     """Streaming corpus scan with a RUNNING per-query top-k: returns
     (vals [B, k], local ids [B, k]) without ever assembling the [B, N]
     score matrix.
@@ -457,8 +427,7 @@ def maxsim_topk_chunked(q: jax.Array, docs: jax.Array,
     k = min(k, N)
     if chunk <= 0 or chunk >= N:
         s = maxsim_scores(q, docs, q_mask, doc_mask, scales, doc_valid,
-                          impl=impl, block_n=block_n, block_d=block_d,
-                          interpret=interpret)
+                          impl=impl, block_n=block_n, interpret=interpret)
         return jax.lax.top_k(s, k)
     if doc_valid is None:
         doc_valid = jnp.ones((N,), bool)
@@ -467,7 +436,7 @@ def maxsim_topk_chunked(q: jax.Array, docs: jax.Array,
     n_blocks = docs.shape[0] // chunk
     kb = min(k, chunk)
     call = functools.partial(maxsim_scores, impl=impl, block_n=block_n,
-                             block_d=block_d, interpret=interpret)
+                             interpret=interpret)
     # mask-less stores keep doc_mask=None per chunk (padding rows are
     # excluded via the False-padded doc_valid) — never an [N, D] ones
     xs = {"docs": docs.reshape(n_blocks, chunk, *docs.shape[1:]),
@@ -531,17 +500,73 @@ def quantize_int8(docs: jax.Array, eps: float = 1e-9, chunk: int = 0):
 
 
 # ---------------------------------------------------------------------------
+# served shapes (what the TPU probes and tests/test_chip_compile.py compile)
+# ---------------------------------------------------------------------------
+
+# colpali's served widths (configs/colpali.py): pooled rows (32 row means
+# through conv1d -> 34) and full-resolution rows (the 32x32 patch grid),
+# d=128; query blocks up to 64 x 32 tokens; rerank L=256; 256 IVF centroids
+D_POOLED, D_FULL, DIM = 34, 1024, 128
+N_POOLED, N_FULL = 65536, 8192
+Q_TOKENS, RERANK_L, N_CENTROIDS = 32, 256, 256
+
+
+def _doc_shapes(n: int, rows: int, dtype) -> list:
+    shapes = [((n, rows, DIM), dtype), ((n, rows), jnp.float32)]
+    if dtype == jnp.int8:
+        shapes.append(((n, rows), jnp.float32))          # per-row scales
+    return shapes
+
+
+def _scan(q, qm, docs, dm, scales=None):
+    return maxsim_scores(q, docs, qm, dm, scales, impl="pallas",
+                         interpret=False)
+
+
+def _rerank(q, qm, rows, docs, dm, scales=None):
+    return maxsim_rerank(q, docs, rows, qm, dm, scales, impl="pallas",
+                         interpret=False)
+
+
+def _route(q, qm, cents):
+    return centroid_scores(q, cents, qm, impl="pallas", interpret=False)
+
+
+def served_instances() -> dict:
+    """{case: (family, fn, [(shape, dtype), ...])}: the native kernel
+    instances this module serves at colpali width, bf16 and int8 docs."""
+    out = {}
+    for n, rows, dtype, b in ((N_POOLED, D_POOLED, jnp.bfloat16, 64),
+                              (N_POOLED, D_POOLED, jnp.int8, 16),
+                              (N_FULL, D_FULL, jnp.bfloat16, 16),
+                              (N_FULL, D_FULL, jnp.int8, 64)):
+        out[f"scan-D{rows}-{jnp.dtype(dtype).name}-B{b}"] = (
+            "maxsim_scan", _scan,
+            [((b, Q_TOKENS, DIM), jnp.float32), ((b, Q_TOKENS), jnp.float32),
+             *_doc_shapes(n, rows, dtype)])
+    for dtype in (jnp.bfloat16, jnp.int8):
+        out[f"rerank-L{RERANK_L}-{jnp.dtype(dtype).name}"] = (
+            "maxsim_rerank", _rerank,
+            [((16, Q_TOKENS, DIM), jnp.float32),
+             ((16, Q_TOKENS), jnp.float32), ((16, RERANK_L), jnp.int32),
+             *_doc_shapes(N_FULL, D_FULL, dtype)])
+    out[f"route-K{N_CENTROIDS}"] = (
+        "ivf_route", _route,
+        [((64, Q_TOKENS, DIM), jnp.float32), ((64, Q_TOKENS), jnp.float32),
+         ((N_CENTROIDS, DIM), jnp.float32)])
+    return out
+
+
+# ---------------------------------------------------------------------------
 # dispatch-registry records (THE policy surface — see kernels.dispatch)
 # ---------------------------------------------------------------------------
 
 # the scan kernel's interpret mode is a sanctioned off-TPU serving path
 # (kernel-body semantics validated on this host, compiled natively on TPU),
-# so interpret_ok=True; the Pallas impls count as "kernel-routed" —
-# "pallas_db" is the native-TPU double-buffered variant the chunked scan
-# promotes itself to (see maxsim_scores_chunked/maxsim_scores_pipelined)
+# so interpret_ok=True
 DSP.register(DSP.KernelOp(
     name="maxsim_scan", probe=_probe_scan, fallback="ref",
-    interpret_ok=True, kernel_impls=frozenset({"pallas", "pallas_db"})))
+    interpret_ok=True, kernel_impls=frozenset({"pallas"})))
 
 # interpret-mode Pallas is a correctness tool for the gather kernel, not a
 # serving path: off-TPU the fused path serves its jnp twin. Both fused

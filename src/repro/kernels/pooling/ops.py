@@ -177,10 +177,32 @@ def pool_pages_grouped(x: jax.Array, mask: jax.Array, p2: jax.Array,
     return out
 
 
+def _pool(x, m, pm):
+    return pool_pages_fused(x, m, pm, impl="pallas", interpret=False)
+
+
+def served_instances() -> dict:
+    """{case: (family, fn, [(shape, dtype), ...])}: the native pooling
+    kernel at the page widths of the static-geometry configs (colpali
+    S=1024, colsmol S=832) over a 64-page ingest batch."""
+    from repro.configs import get_config
+    out = {}
+    for arch in ("colpali", "colsmol"):
+        cfg = get_config(arch)
+        mat, _ = pooling_matrix_static(cfg)
+        out[f"pool-{arch}-S{cfg.n_patches}"] = (
+            "pooling", _pool,
+            [((64, cfg.n_patches, cfg.out_dim), jnp.float32),
+             ((64, cfg.n_patches), jnp.float32), (mat.shape, jnp.float32)])
+    return out
+
+
 def _probe_pool() -> bool:
-    """Trace a tiny fused-pooling kernel instance (the ``pooling``
-    dispatch-registry probe; callers resolve to the jnp twin when it
-    fails)."""
+    """The ``pooling`` dispatch-registry probe: the served instances
+    compiled on TPU, a tiny interpreted instance elsewhere (where callers
+    resolve to the jnp twin when it fails)."""
+    if not default_interpret():
+        return DSP.compile_served(served_instances(), "pooling")
     x = jnp.zeros((1, 8, 128), jnp.float32)
     m = jnp.ones((1, 8), jnp.float32)
     pm = jnp.ones((2, 8), jnp.float32)
@@ -215,12 +237,8 @@ def pool_pages_fused(x: jax.Array, mask: jax.Array, pool_mat: jax.Array,
     DSP.record("pooling", impl)
     if impl == "ref":
         return pool_ref(x, mask, pool_mat, l2_norm=l2_norm)
-    S = x.shape[1]
-    bs = block_s if block_s > 0 else (S if S % 2 else min(S, 512))
-    while S % bs:
-        bs //= 2
-    return pool_pallas(x, mask, pool_mat, block_s=max(bs, 1),
-                       l2_norm=l2_norm, interpret=interpret)
+    return pool_pallas(x, mask, pool_mat, block_s=block_s, l2_norm=l2_norm,
+                       interpret=interpret)
 
 
 # interpret-mode Pallas is a correctness tool, not an ingest path: off-TPU

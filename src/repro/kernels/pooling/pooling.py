@@ -36,11 +36,13 @@ def _pool_kernel(x_ref, m_ref, p_ref, out_ref, num_ref, den_ref,
         den_ref[...] = jnp.zeros_like(den_ref)
 
     x = x_ref[...].astype(jnp.float32)            # [bs, d]
-    m = m_ref[...].astype(jnp.float32)            # [bs]
-    p = p_ref[...].astype(jnp.float32)            # [n_out, bs]
-    xm = x * m[:, None]
-    num_ref[...] += jax.lax.dot(p, xm, preferred_element_type=jnp.float32)
-    den_ref[...] += p @ m[:, None]                # [n_out, 1]
+    m = m_ref[...].astype(jnp.float32)            # [1, bs] (lane row)
+    # the mask folds into the operator's columns: (P * m) @ x is
+    # P @ (x * m) without a per-token column broadcast
+    pm = p_ref[...].astype(jnp.float32) * m       # [n_out, bs]
+    num_ref[...] += jax.lax.dot(pm, x, precision=jax.lax.Precision.HIGHEST,
+                                preferred_element_type=jnp.float32)
+    den_ref[...] += jnp.sum(pm, axis=1, keepdims=True)    # [n_out, 1]
 
     @pl.when(si == n_s_blocks - 1)
     def _finish():
@@ -51,14 +53,26 @@ def _pool_kernel(x_ref, m_ref, p_ref, out_ref, num_ref, den_ref,
         out_ref[...] = out.astype(out_ref.dtype)
 
 
+def page_block(S: int) -> int:
+    """Default S tile: the largest lane-tile multiple (128) up to 512 that
+    divides S, else the whole page (colsmol's S=832 has no such divisor)."""
+    for bs in (512, 384, 256, 128):
+        if S % bs == 0:
+            return bs
+    return S
+
+
 def pool_pallas(x: jax.Array, mask: jax.Array, pool_mat: jax.Array,
                 *, block_s: int = 0, l2_norm: bool = True,
                 interpret: bool = True) -> jax.Array:
-    """x [B,S,d], mask [B,S] f32, pool_mat [n_out,S] -> [B, n_out, d] f32."""
+    """x [B,S,d], mask [B,S] f32, pool_mat [n_out,S] -> [B, n_out, d] f32.
+
+    The mask travels as [B, 1, S] so its block's two minor dims are
+    (1, bs): a whole array dim and a lane-tile multiple (or all of S)."""
     B, S, d = x.shape
     n_out, S2 = pool_mat.shape
     assert S == S2, (S, S2)
-    bs = block_s if block_s > 0 else min(S, 512)
+    bs = block_s if block_s > 0 else page_block(S)
     assert S % bs == 0, (S, bs)
     n_s_blocks = S // bs
 
@@ -69,7 +83,7 @@ def pool_pallas(x: jax.Array, mask: jax.Array, pool_mat: jax.Array,
         grid=(B, n_s_blocks),
         in_specs=[
             pl.BlockSpec((None, bs, d), lambda b, s: (b, s, 0)),
-            pl.BlockSpec((None, bs), lambda b, s: (b, s)),
+            pl.BlockSpec((None, 1, bs), lambda b, s: (b, 0, s)),
             pl.BlockSpec((n_out, bs), lambda b, s: (0, s)),
         ],
         out_specs=pl.BlockSpec((None, n_out, d), lambda b, s: (b, 0, 0)),
@@ -77,4 +91,4 @@ def pool_pallas(x: jax.Array, mask: jax.Array, pool_mat: jax.Array,
         scratch_shapes=[pltpu.VMEM((n_out, d), jnp.float32),
                         pltpu.VMEM((n_out, 1), jnp.float32)],
         interpret=interpret,
-    )(x, mask.astype(jnp.float32), pool_mat.astype(jnp.float32))
+    )(x, mask.astype(jnp.float32)[:, None, :], pool_mat.astype(jnp.float32))

@@ -23,7 +23,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.configs import get_config, get_shapes
 from repro.distributed.sharding import ShardingPolicy
@@ -266,7 +266,7 @@ def build_gnn_cell(arch: str, shape, mesh, variant: str = "base") -> Cell:
                           P(dp_axes, tp_axes), P(dp_axes, tp_axes),
                           P(dp_axes), P(dp_axes), P(dp_axes),
                           P(dp_axes), P(dp_axes), P(dp_axes)),
-                out_specs=P(), check_rep=False,
+                out_specs=P(), check_vma=False,
             )(b["feat"], b["pos"], b["labels"], b["lmask"], b["esrc"],
               b["edstg"], b["emask"], b["rdst"], b["rsrcg"], b["rmask"])
 
@@ -360,7 +360,7 @@ def build_gnn_cell(arch: str, shape, mesh, variant: str = "base") -> Cell:
                       P(flat_axes), P(flat_axes),  # labels, lmask
                       P(flat_axes), P(flat_axes), P(flat_axes),
                       P(flat_axes), P(flat_axes), P(flat_axes)),
-            out_specs=P(), check_rep=False,
+            out_specs=P(), check_vma=False,
         )(b["feat"], b["pos"], b["labels"], b["lmask"], b["esrc"],
           b["edstg"], b["emask"], b["rdst"], b["rsrcg"], b["rmask"])
 

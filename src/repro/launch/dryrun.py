@@ -132,6 +132,8 @@ def main():
     ap.add_argument("--force", action="store_true",
                     help="recompute cells already in the results file")
     args = ap.parse_args()
+    from repro.launch.runtime import device_line
+    print(device_line(), flush=True)
 
     from repro.launch.mesh import make_production_mesh, make_mesh
     from repro.configs import ASSIGNED_ARCHS, PAPER_ARCHS, get_config, \

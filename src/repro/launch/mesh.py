@@ -4,27 +4,18 @@ Defined as FUNCTIONS (never module-level constants) so importing this module
 never touches jax device state — the dry-run must set XLA_FLAGS before any
 jax initialisation.
 
-``make_mesh`` is version-compat: ``jax.sharding.AxisType`` (and the
-``axis_types=`` kwarg of ``jax.make_mesh``) only exist on newer jax; on
-older versions the kwarg is omitted, which yields the same Auto-typed axes.
-All mesh construction in this repo goes through these helpers — never call
-``jax.make_mesh(axis_types=...)`` directly.
+Every axis is ``AxisType.Auto``; all mesh construction in this repo goes
+through these helpers.
 """
 from __future__ import annotations
 
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_mesh(shape: tuple, axes: tuple):
     shape, axes = tuple(shape), tuple(axes)
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

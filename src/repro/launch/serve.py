@@ -69,7 +69,9 @@ wall budget under which the engine serves from resident segments only
 (results flagged degraded) instead of blocking on cold promotions. On
 SIGTERM/SIGINT the launcher exits GRACEFULLY: drain the frontend's
 queued requests, take a final generation-stamped snapshot (with
-``--snapshot-dir``), report shed/degraded/retry counters, exit 0.
+``--snapshot-dir``), report shed/degraded/retry counters, exit 0 — or 1
+when a request errored and no ``--fault-plan`` was armed, as every mode
+does.
 """
 from __future__ import annotations
 
@@ -102,7 +104,7 @@ def _install_signals():
 
 
 def _graceful_exit(args, reason: str) -> None:
-    """Drain, snapshot, report, exit 0 — a SIGTERM'd server finishes the
+    """Drain, snapshot, report, exit — a SIGTERM'd server finishes the
     work it admitted and leaves a corpus the next process cold-starts
     from (the restart-without-re-ingest loop)."""
     print(f"\n{reason}: graceful shutdown")
@@ -128,7 +130,7 @@ def _graceful_exit(args, reason: str) -> None:
         print(f"  final snapshot -> {path}")
     if eng is not None:
         eng.close()
-    sys.exit(0)
+    sys.exit(_exit_status(args, fe.stats["errors"] if fe is not None else 0))
 
 
 def _multi_tenant_retriever(args, cfg, bench, stages, int8_on, **kw):
@@ -295,9 +297,20 @@ def _make_ragged_requests(bench, n_req: int, rng, min_tokens: int = 3):
     return reqs
 
 
+def _exit_status(args, errors: int) -> int:
+    """Process exit status after serving: non-zero when any request
+    errored, unless ``--fault-plan`` armed the fault injector on purpose
+    (then errors are the drill's expected outcome, reported above)."""
+    if errors and not args.fault_plan:
+        print(f"FAILED: {errors} request(s) errored", file=sys.stderr)
+        return 1
+    return 0
+
+
 def _run_traffic(args, cfg, bench, store, stages, int8_on):
     """Open-loop Poisson traffic of ragged single queries through the
-    shape-bucketed micro-batching frontend; tail latency + QPS report."""
+    shape-bucketed micro-batching frontend; tail latency + QPS report.
+    Returns the number of requests that errored."""
     import jax.numpy as jnp
     from repro.retrieval import tracing
     from repro.retrieval.frontend import ServingFrontend, replay_open_loop
@@ -358,13 +371,15 @@ def _run_traffic(args, cfg, bench, store, stages, int8_on):
           f"QPS={qps:.1f} (static fixed-shape QPS={static_qps:.1f}, "
           f"ratio {qps/static_qps:.2f}x)")
     print(f"  dispatches={fe.stats['dispatches']}  "
-          f"rows/dispatch={fe.stats['rows_real']/fe.stats['dispatches']:.1f}  "
+          f"rows/dispatch="
+          f"{fe.stats['rows_real']/max(fe.stats['dispatches'], 1):.1f}  "
           f"padded rows={fe.stats['rows_padded']}  "
           f"cache hits={fe.stats['cache_hits']}  "
           f"rejected={fe.stats['rejected']}  "
           f"shed={fe.stats['shed']}  degraded={fe.stats['degraded']}  "
           f"errors={fe.stats['errors']}  "
           f"steady-state retraces={retraces} (expect 0)")
+    return fe.stats["errors"]
 
 
 def _run_ingest(args, cfg, bench, store, stages, int8_on):
@@ -461,6 +476,7 @@ def main():
     from repro.configs import get_config
     from repro.core import multistage as MST
     from repro.data.synthetic import make_benchmark
+    from repro.launch.runtime import device_line, setup_compile_cache
     from repro.retrieval.store import build_store, quantize_store
 
     ap = argparse.ArgumentParser()
@@ -558,6 +574,8 @@ def main():
                          "'transfer_fail_rate=0.05,kill_worker_at=3,"
                          "seed=7')")
     args = ap.parse_args()
+    setup_compile_cache()
+    print(device_line(), flush=True)
     _install_signals()
 
     cfg = get_config(args.arch)
@@ -607,15 +625,18 @@ def main():
     if store is not None:
         print(f"indexed {store.n_docs} pages in {time.time()-t0:.2f}s "
               f"(named vectors: {sorted(store.dims())})")
+    errors = 0
     try:
         if args.traffic > 0:
-            _run_traffic(args, cfg, bench, store, stages, int8_on)
+            errors = _run_traffic(args, cfg, bench, store, stages, int8_on)
         elif args.ingest_batches > 0:
             _run_ingest(args, cfg, bench, store, stages, int8_on)
         else:
             _run_static(args, cfg, bench, store, stages, int8_on)
     except _Shutdown as e:
         _graceful_exit(args, str(e))
+    if _exit_status(args, errors):
+        sys.exit(1)
 
 
 if __name__ == "__main__":

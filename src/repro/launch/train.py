@@ -37,6 +37,7 @@ def main():
     import jax.numpy as jnp
     from repro.configs import get_config
     from repro.distributed.sharding import ShardingPolicy
+    from repro.launch.runtime import device_line
     from repro.models import transformer as T
     from repro.training import checkpoint as CKPT
     from repro.training import optimizer as OPT
@@ -55,6 +56,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    print(device_line(), flush=True)
 
     cfg = get_config(args.arch)
     assert cfg.family == "lm", "train.py drives the LM family; see examples/"

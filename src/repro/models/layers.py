@@ -282,7 +282,7 @@ def moe_ragged_ep(cfg, p: dict, x: jax.Array, shard) -> jax.Array:
     Capacity = 1.25x the expected local assignment count; overflow drops
     (standard GShard-style capacity semantics).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     moe = cfg.moe
@@ -336,7 +336,7 @@ def moe_ragged_ep(cfg, p: dict, x: jax.Array, shard) -> jax.Array:
         in_specs=(P(dp_axes, None, None), P(None, None),
                   P(tp_ax, None, None), P(tp_ax, None, None),
                   P(tp_ax, None, None)),
-        out_specs=P(dp_axes, None, None), check_rep=False,
+        out_specs=P(dp_axes, None, None), check_vma=False,
     )(x, p["router"], p["w1"], p["w3"], p["w2"])
 
 

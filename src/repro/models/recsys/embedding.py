@@ -24,7 +24,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 @dataclass(frozen=True)
@@ -131,7 +131,7 @@ def lookup_shardmap(layout: EmbeddingLayout, params: dict, idx: jax.Array,
             local, mesh=mesh,
             in_specs=(P(tp_ax, None), P()),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(params["big"], gid)
         out = out.at[:, list(layout.big_fields)].set(vecs)
     if layout.small_fields:

@@ -64,7 +64,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core import maxsim as MS
 from repro.core.multistage import DEFAULT_SCAN_TOPK_CHUNK, Stage
@@ -119,8 +119,9 @@ def _dispatch_scan(stage: Stage, vecs, mask, q, q_mask, scales,
                    impl: str, interpret: bool, doc_valid=None):
     """Score the full-corpus scan stage per the stage's dispatch policy.
 
-    use_kernel routes to the Pallas streaming kernel (or its jnp twin when
-    Pallas is unavailable — ``impl`` is resolved once at build time);
+    use_kernel routes to the Pallas streaming kernel (``impl`` is resolved
+    once at build time: native on TPU, interpreted or the reference off
+    it);
     otherwise the core.maxsim reference runs, chunked when stage.chunk > 0
     so the [B, N, Q, D] similarity intermediate is bounded at
     [B, chunk, Q, D]. [n_docs, D, d] -> [B, n_docs]. ``doc_valid`` [N] bool
@@ -515,7 +516,7 @@ def _build_body(mesh: Mesh | None, stages: tuple, capacities: tuple,
         fn = shard_map(body, mesh=mesh,
                        in_specs=(specs, P(), P(), (P(), P(), P())),
                        out_specs=(P(), P()),
-                       check_rep=False)
+                       check_vma=False)
         return fn(stores, q, q_mask, fspec)
 
     return searcher

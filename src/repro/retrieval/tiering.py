@@ -26,8 +26,8 @@ toolkit exists to remove (paper §1). This module lifts that cap:
   current segment's MaxSim compute, because JAX dispatch is async and
   the worker's ``device_put`` runs off the critical path. The
   double-buffering at CHUNK granularity — HBM->VMEM inside the scan
-  kernel — is the same idea one level down
-  (``kernels.maxsim.maxsim.maxsim_pipelined``).
+  kernel — is the same idea one level down (the Pallas grid pipeline
+  of ``kernels.maxsim.maxsim.maxsim_pallas``).
 - **snapshot/restore** — ``snapshot``/``restore_store`` persist the full
   ``SegmentedStore`` (arrays + schema + slot maps + tenant/filter/IVF
   companions + router policy) through ``training/checkpoint.py``'s

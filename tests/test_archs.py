@@ -171,7 +171,7 @@ def test_sharded_edges_match_local(rng):
 
     # single-shard ShardedEdges: exchange is identity over a 1-device axis
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.launch.mesh import make_mesh
     parts = partition_edges(src, dst, N, 1)
     mesh = make_mesh((1,), ("x",))
@@ -188,7 +188,7 @@ def test_sharded_edges_match_local(rng):
                 n_local=N, shard_offset=jnp.int32(0), axis_names=("x",))
             return E.forward(cfg, params, plan, feat, pos)
         return shard_map(body, mesh=mesh, in_specs=(P(), P()),
-                         out_specs=P(), check_rep=False)(feat, pos)
+                         out_specs=P(), check_vma=False)(feat, pos)
 
     out_sharded = run(feat, pos)
     np.testing.assert_allclose(np.asarray(out_local),

@@ -497,6 +497,34 @@ def test_resolve_policy_matrix():
         assert dispatch.resolve("pooling", True)[0] in ("jnp", "ref")
 
 
+def _probe_error():
+    raise RuntimeError("Mosaic refused the kernel")
+
+
+@pytest.mark.parametrize("probe,expect", [
+    (lambda: True, ("pallas", False)),
+    (lambda: False, RuntimeError),
+    (_probe_error, RuntimeError),
+])
+def test_resolve_on_tpu_never_falls_back(monkeypatch, probe, expect):
+    """On a TPU backend resolve(name, True) is the native kernel or an
+    error: a failed probe must never route to the family's fallback."""
+    monkeypatch.setattr(dispatch, "default_interpret", lambda: False)
+    monkeypatch.setitem(dispatch._REGISTRY, "tpu_op", dispatch.KernelOp(
+        name="tpu_op", probe=probe, fallback="jnp"))
+    monkeypatch.delitem(dispatch._AVAILABLE, "tpu_op", raising=False)
+    try:
+        if isinstance(expect, tuple):
+            assert dispatch.resolve("tpu_op", True) == expect
+        else:
+            with pytest.raises(expect):
+                dispatch.resolve("tpu_op", True)
+        assert dispatch.resolve("tpu_op", False) == ("ref", True)
+    finally:
+        dispatch._AVAILABLE.pop("tpu_op", None)
+        dispatch._COUNTS.pop("tpu_op", None)
+
+
 def test_probe_exempt_from_dispatch_counters():
     """available() must never bump the observed-routing counters — a CI
     gate diffing kernel_dispatch_count would otherwise pass on a probe

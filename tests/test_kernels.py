@@ -21,14 +21,14 @@ from repro.configs import get_config
     (1, 8, 8, 32, 128),
     (3, 10, 24, 96, 128),
     (2, 17, 40, 64, 64),      # Q not sublane-aligned -> padding path
-    (4, 32, 16, 130, 128),    # D not block-aligned
+    (4, 32, 16, 130, 128),    # D not sublane-aligned
 ])
 def test_maxsim_shapes(rng, B, Q, N, D, d):
     q = jnp.asarray(rng.normal(size=(B, Q, d)), jnp.float32)
     docs = jnp.asarray(rng.normal(size=(N, D, d)), jnp.float32)
     qm = jnp.asarray(rng.random((B, Q)) > 0.2, jnp.float32)
     dm = jnp.asarray(rng.random((N, D)) > 0.1, jnp.float32)
-    out = maxsim_scores(q, docs, qm, dm, impl="pallas", block_n=8, block_d=32)
+    out = maxsim_scores(q, docs, qm, dm, impl="pallas", block_n=8)
     ref = maxsim_ref(q, qm, docs, dm)
     np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4)
 
@@ -37,7 +37,7 @@ def test_maxsim_shapes(rng, B, Q, N, D, d):
 def test_maxsim_dtypes(rng, dtype):
     q = jnp.asarray(rng.normal(size=(2, 8, 128)), dtype)
     docs = jnp.asarray(rng.normal(size=(16, 64, 128)), dtype)
-    out = maxsim_scores(q, docs, impl="pallas", block_n=8, block_d=64)
+    out = maxsim_scores(q, docs, impl="pallas", block_n=8)
     ref = maxsim_ref(q, jnp.ones((2, 8)), docs, jnp.ones((16, 64)))
     tol = 1e-4 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(out, ref, rtol=tol, atol=tol)
@@ -48,7 +48,7 @@ def test_maxsim_int8(rng):
     docs = jnp.asarray(rng.normal(size=(16, 64, 128)), jnp.float32)
     codes, scales = quantize_int8(docs)
     out = maxsim_scores(q, codes.astype(jnp.float32), None, None, scales,
-                        impl="pallas", block_n=8, block_d=64)
+                        impl="pallas", block_n=8)
     ref = maxsim_ref(q, jnp.ones((2, 8)), docs, jnp.ones((16, 64)))
     # int8 quantisation error bound, not kernel error
     np.testing.assert_allclose(out, ref, rtol=2e-2, atol=2e-1)
@@ -60,8 +60,7 @@ def test_maxsim_fully_masked_doc(rng):
     q = jnp.asarray(rng.normal(size=(1, 8, 128)), jnp.float32)
     docs = jnp.asarray(rng.normal(size=(8, 16, 128)), jnp.float32)
     dm = jnp.ones((8, 16), jnp.float32).at[3].set(0.0)
-    out = maxsim_scores(q, docs, None, dm, impl="pallas", block_n=8,
-                        block_d=16)
+    out = maxsim_scores(q, docs, None, dm, impl="pallas", block_n=8)
     assert np.isfinite(np.asarray(out))[:, :3].all()
     assert np.asarray(out)[0, 3] < -1e20        # masked doc sinks
 
@@ -191,8 +190,7 @@ def test_topk_chunked_int8_pallas(rng):
                       impl="ref")
     ev, ei = jax.lax.top_k(s, 6)
     v, i = maxsim_topk_chunked(q, codes, None, None, scales, None, k=6,
-                               chunk=8, impl="pallas", block_n=8,
-                               block_d=16)
+                               chunk=8, impl="pallas", block_n=8)
     np.testing.assert_array_equal(np.asarray(i), np.asarray(ei))
     np.testing.assert_allclose(np.asarray(v), np.asarray(ev),
                                rtol=1e-4, atol=1e-4)
