@@ -413,9 +413,9 @@ def rerank_kernel_vs_ref(table: dict, quick: bool = False):
     s_ref, i_ref = r.search(q, qm, stages=ref_stages)
     np.testing.assert_array_equal(np.asarray(i_ref), io)
     np.testing.assert_array_equal(np.asarray(s_ref), so)   # bitwise
-    before_fused = KOPS.fused_rerank_trace_count()
+    before_fused = DSP.kernel_dispatch_count("maxsim_rerank")
     s_fus, i_fus = r.search(q, qm, stages=fused_stages)
-    fused_traces = KOPS.fused_rerank_trace_count() - before_fused
+    fused_traces = DSP.kernel_dispatch_count("maxsim_rerank") - before_fused
     np.testing.assert_array_equal(np.asarray(i_fus), io)
     np.testing.assert_allclose(np.asarray(s_fus), so, rtol=1e-4, atol=1e-4)
     assert fused_traces > 0, (
@@ -671,13 +671,13 @@ def ingest_throughput(table: dict, quick: bool = False):
     # back to the reference chain leaves the counter untouched — the CI
     # gate asserts on this
     kpipe = IngestPipeline.for_config(cfg, use_kernel=True)
-    before_fused = POPS.fused_pool_trace_count()
+    before_fused = DSP.kernel_dispatch_count("pooling")
     jax.eval_shape(
         lambda p, t: kpipe._index_arrays(p, t, None),
         jax.ShapeDtypeStruct((8, cfg.seq_len, cfg.out_dim), jnp.float32),
         jax.ShapeDtypeStruct((cfg.seq_len,), jnp.int32))
     out["kernel_fused_pool_traces"] = \
-        POPS.fused_pool_trace_count() - before_fused
+        DSP.kernel_dispatch_count("pooling") - before_fused
     out["kernel_pool_path"] = kpipe.pool_path
 
     # ---- section 1: pooling-stage dispatch A/B ----

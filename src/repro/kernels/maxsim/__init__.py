@@ -1,5 +1,4 @@
 from repro.kernels.maxsim.ops import (default_interpret,
-                                      fused_rerank_trace_count,
                                       maxsim_rerank, maxsim_scores,
                                       maxsim_scores_chunked,
                                       maxsim_topk_chunked, pallas_available,

@@ -177,15 +177,6 @@ def maxsim_scores_chunked(q: jax.Array, docs: jax.Array,
 # fused gather + MaxSim rerank
 # ---------------------------------------------------------------------------
 
-def fused_rerank_trace_count() -> int:
-    """Trace-time dispatches that routed through the FUSED rerank path
-    (the Pallas gather kernel or its jnp twin, not the legacy reference
-    gather) — an OBSERVATIONAL signal the candidate-path benchmark's CI
-    gate diffs (a config-derived flag could not catch a silent fallback).
-    Counted by the ``dispatch`` registry's record hook."""
-    return DSP.kernel_dispatch_count("maxsim_rerank")
-
-
 def _rerank_ref(q, docs, rows, q_mask, doc_mask, scales):
     """The legacy gather-then-score path: per-query ``jnp.take`` + the
     ``core.maxsim.maxsim_scan`` math — bitwise the ``multistage``

@@ -218,16 +218,6 @@ def pallas_available() -> bool:
     return DSP.available("pooling")
 
 
-def fused_pool_trace_count() -> int:
-    """Trace-time dispatches that routed through the FUSED pooling
-    operator (the Pallas kernel or either jnp evaluation of the same
-    single-normalisation matrix formulation — ``pool_ref`` and the
-    factored ``pool_pages_grouped``; the functional ``core.pooling``
-    reference chain never records). The OBSERVATIONAL signal the ingest
-    benchmark's CI gate diffs, counted by the ``dispatch`` registry."""
-    return DSP.kernel_dispatch_count("pooling")
-
-
 @functools.partial(jax.jit, static_argnames=("impl", "block_s", "l2_norm",
                                              "interpret"))
 def pool_pages_fused(x: jax.Array, mask: jax.Array, pool_mat: jax.Array,

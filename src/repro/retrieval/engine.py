@@ -76,7 +76,8 @@ from repro.retrieval.store import (ROUTING_KEYS, VALIDITY_KEY,
                                    routing_arrays, scan_arrays)
 from repro.retrieval.topk import (allgather_topk, gathered_merge_topk,
                                   merge_topk)
-from repro.retrieval.tracing import record_trace
+from repro.retrieval.tracing import (SCOPE_MASK, SCOPE_RERANK, SCOPE_SCAN,
+                                     record_trace)
 
 NEG = -1e30
 INT8_REF_CHUNK = 1024      # fallback scan chunk for int8 stores in ref mode
@@ -350,37 +351,40 @@ def _build_body(mesh: Mesh | None, stages: tuple, capacities: tuple,
             # one effective mask per segment — doc_valid AND the request's
             # tenant/filter terms — computed once and threaded through
             # every stage
-            effs = tuple(effective_validity(s, fspec) for s in stores)
+            with jax.named_scope(SCOPE_MASK):
+                effs = tuple(effective_validity(s, fspec) for s in stores)
             scores = cand = None
             for si, stage in enumerate(stages):
-                if si == 0:
-                    parts_v, parts_i = [], []
-                    for store, eff, cap, off in zip(stores, effs, capacities,
-                                                    offsets):
-                        v, i = _segment_stage0(
-                            stage, store, eff, cap, off, q, q_mask,
-                            routed=routed, impl=impl, interpret=interpret,
-                            rt_impl=rt_impl, rt_interpret=rt_interpret,
-                            r0_impl=r0_impl, r0_interpret=r0_interpret)
-                        parts_v.append(v)
-                        parts_i.append(i)
-                    scores, cand = merge_topk(
-                        jnp.concatenate(parts_v, axis=1),
-                        jnp.concatenate(parts_i, axis=1),
-                        min(stage.k, total_cap))
-                else:
-                    s_all = None
-                    for store, eff, cap, off in zip(stores, effs, capacities,
-                                                    offsets):
-                        s = _segment_rerank(stage, store, eff, cap, off,
-                                            q, q_mask, cand,
-                                            *rerank_dispatch(stage))
-                        # each candidate lives in exactly one segment; the
-                        # others scored it NEG, so max == owner's score
-                        s_all = s if s_all is None else jnp.maximum(s_all, s)
-                    k = min(stage.k, cand.shape[1])
-                    scores, sel = jax.lax.top_k(s_all, k)
-                    cand = jnp.take_along_axis(cand, sel, axis=1)
+                with jax.named_scope(SCOPE_SCAN if si == 0 else SCOPE_RERANK):
+                    if si == 0:
+                        parts_v, parts_i = [], []
+                        for store, eff, cap, off in zip(
+                                stores, effs, capacities, offsets):
+                            v, i = _segment_stage0(
+                                stage, store, eff, cap, off, q, q_mask,
+                                routed=routed, impl=impl, interpret=interpret,
+                                rt_impl=rt_impl, rt_interpret=rt_interpret,
+                                r0_impl=r0_impl, r0_interpret=r0_interpret)
+                            parts_v.append(v)
+                            parts_i.append(i)
+                        scores, cand = merge_topk(
+                            jnp.concatenate(parts_v, axis=1),
+                            jnp.concatenate(parts_i, axis=1),
+                            min(stage.k, total_cap))
+                    else:
+                        s_all = None
+                        for store, eff, cap, off in zip(
+                                stores, effs, capacities, offsets):
+                            s = _segment_rerank(stage, store, eff, cap, off,
+                                                q, q_mask, cand,
+                                                *rerank_dispatch(stage))
+                            # each candidate lives in exactly one segment; the
+                            # others scored it NEG, so max == owner's score
+                            s_all = s if s_all is None \
+                                else jnp.maximum(s_all, s)
+                        k = min(stage.k, cand.shape[1])
+                        scores, sel = jax.lax.top_k(s_all, k)
+                        cand = jnp.take_along_axis(cand, sel, axis=1)
             return scores, cand
         return local_body
 
@@ -397,112 +401,117 @@ def _build_body(mesh: Mesh | None, stages: tuple, capacities: tuple,
         shard_idx = jax.lax.axis_index(axes)
         # per-segment effective mask over the LOCAL slab (the companions
         # shard along docs with everything else; fspec is replicated)
-        effs = tuple(effective_validity(s, fspec) for s in stores)
+        with jax.named_scope(SCOPE_MASK):
+            effs = tuple(effective_validity(s, fspec) for s in stores)
         scores = cand = None
         for si, stage in enumerate(stages):
-            if si == 0:
-                parts_v, parts_i = [], []
-                for store, eff, cap, off in zip(stores, effs, capacities,
-                                                offsets):
-                    n_local = cap // n_shards
-                    if routed:
-                        # replicated routing inputs -> every shard derives
-                        # the identical candidate rows, then the rerank
-                        # stages' mine/compact machinery scores only the
-                        # owned slots. cap_slots >= n_local whenever
-                        # K*C >= capacity (the member-width invariant), so
-                        # the compaction is EXACT at n_probe == K — parity
-                        # mode survives sharding.
-                        rows = _routed_rows(store, stage, q, q_mask,
-                                            rt_impl, rt_interpret)
-                        R = rows.shape[1]
-                        rclip = jnp.clip(rows, 0, cap - 1)
-                        cap_slots = min(R, max(1, -(-R // n_shards))
-                                        * rerank_overcommit)
-                        mine = (rows >= 0) & (rclip // n_local == shard_idx)
-                        order = jnp.argsort(~mine, axis=1)[:, :cap_slots]
-                        rsel = jnp.take_along_axis(rclip % n_local, order,
-                                                   axis=1)
-                        gsel = jnp.take_along_axis(rclip, order, axis=1)
-                        ok = jnp.take_along_axis(mine, order, axis=1)
-                        if eff is not None:
-                            ok = ok & jnp.take(eff, rsel, axis=0)
-                        s = _score_candidates(
-                            *_scan_arrays(store, stage), q, q_mask,
-                            rsel, ok, r0_impl, r0_interpret)
-                        v, sel = jax.lax.top_k(
-                            s, min(stage.k, cap, cap_slots))
-                        gi = jnp.where(
-                            jnp.take_along_axis(ok, sel, axis=1),
-                            jnp.take_along_axis(gsel, sel, axis=1) + off,
-                            -1)
-                        v, i = gathered_merge_topk(v, gi,
-                                                   min(stage.k, cap), axes)
+            with jax.named_scope(SCOPE_SCAN if si == 0 else SCOPE_RERANK):
+                if si == 0:
+                    parts_v, parts_i = [], []
+                    for store, eff, cap, off in zip(stores, effs, capacities,
+                                                    offsets):
+                        n_local = cap // n_shards
+                        if routed:
+                            # replicated routing inputs -> every shard
+                            # derives the identical candidate rows, then the
+                            # rerank stages' mine/compact machinery scores
+                            # only the owned slots. cap_slots >= n_local
+                            # whenever K*C >= capacity (the member-width
+                            # invariant), so the compaction is EXACT at
+                            # n_probe == K — parity mode survives sharding.
+                            rows = _routed_rows(store, stage, q, q_mask,
+                                                rt_impl, rt_interpret)
+                            R = rows.shape[1]
+                            rclip = jnp.clip(rows, 0, cap - 1)
+                            cap_slots = min(R, max(1, -(-R // n_shards))
+                                            * rerank_overcommit)
+                            mine = (rows >= 0) \
+                                & (rclip // n_local == shard_idx)
+                            order = jnp.argsort(~mine, axis=1)[:, :cap_slots]
+                            rsel = jnp.take_along_axis(rclip % n_local, order,
+                                                       axis=1)
+                            gsel = jnp.take_along_axis(rclip, order, axis=1)
+                            ok = jnp.take_along_axis(mine, order, axis=1)
+                            if eff is not None:
+                                ok = ok & jnp.take(eff, rsel, axis=0)
+                            s = _score_candidates(
+                                *_scan_arrays(store, stage), q, q_mask,
+                                rsel, ok, r0_impl, r0_interpret)
+                            v, sel = jax.lax.top_k(
+                                s, min(stage.k, cap, cap_slots))
+                            gi = jnp.where(
+                                jnp.take_along_axis(ok, sel, axis=1),
+                                jnp.take_along_axis(gsel, sel, axis=1) + off,
+                                -1)
+                            v, i = gathered_merge_topk(v, gi,
+                                                       min(stage.k, cap), axes)
+                            parts_v.append(v)
+                            parts_i.append(i)
+                            continue
+                        vecs, mask, scales = _scan_arrays(store, stage)
+                        if stage.scan_topk:
+                            # streamed per-shard running top-k; ids shift into
+                            # the global slot space before the gather-merge
+                            v, i = _dispatch_scan_topk(
+                                stage, vecs, mask, q, q_mask, scales,
+                                impl, interpret, eff, min(stage.k, cap))
+                            v, i = gathered_merge_topk(
+                                v, i + shard_idx * n_local + off,
+                                min(stage.k, cap), axes)
+                        else:
+                            s_loc = _dispatch_scan(stage, vecs, mask, q,
+                                                   q_mask, scales, impl,
+                                                   interpret)
+                            v, i = allgather_topk(s_loc, min(stage.k, cap),
+                                                  axes, shard_idx, n_local,
+                                                  valid_local=eff,
+                                                  seg_offset=off)
                         parts_v.append(v)
                         parts_i.append(i)
-                        continue
-                    vecs, mask, scales = _scan_arrays(store, stage)
-                    if stage.scan_topk:
-                        # streamed per-shard running top-k; ids shift into
-                        # the global slot space before the gather-merge
-                        v, i = _dispatch_scan_topk(
-                            stage, vecs, mask, q, q_mask, scales,
-                            impl, interpret, eff, min(stage.k, cap))
-                        v, i = gathered_merge_topk(
-                            v, i + shard_idx * n_local + off,
-                            min(stage.k, cap), axes)
-                    else:
-                        s_loc = _dispatch_scan(stage, vecs, mask, q, q_mask,
-                                               scales, impl, interpret)
-                        v, i = allgather_topk(s_loc, min(stage.k, cap),
-                                              axes, shard_idx, n_local,
-                                              valid_local=eff,
-                                              seg_offset=off)
-                    parts_v.append(v)
-                    parts_i.append(i)
-                scores, cand = merge_topk(
-                    jnp.concatenate(parts_v, axis=1),
-                    jnp.concatenate(parts_i, axis=1),
-                    min(stage.k, total_cap))
-            else:
-                L = cand.shape[1]
-                cap_slots = min(L, max(1, -(-L // n_shards))
-                                * rerank_overcommit)
-                parts_v, parts_i = [], []
-                for store, eff, cap, off in zip(stores, effs, capacities,
-                                                offsets):
-                    n_local = cap // n_shards
-                    local = cand - off
-                    in_seg = (local >= 0) & (local < cap)
-                    lclip = jnp.clip(local, 0, cap - 1)
-                    mine = in_seg & (lclip // n_local == shard_idx)
-                    order = jnp.argsort(~mine, axis=1)[:, :cap_slots]
-                    rows = jnp.take_along_axis(lclip % n_local, order, axis=1)
-                    ok = jnp.take_along_axis(mine, order, axis=1)
-                    if eff is not None:
-                        ok = ok & jnp.take(eff, rows, axis=0)
-                    s = _score_candidates(
-                        *rerank_arrays(store, stage.vector),
-                        q, q_mask, rows, ok, *rerank_dispatch(stage))
-                    # merge shards/segments: each candidate scored real on
-                    # exactly one (shard, segment); NEG everywhere else.
-                    # Non-owned copies also DROP their slot id (-1): when
-                    # k exceeds the live candidates, NEG filler wins top-k
-                    # slots, and a filler copy carrying a live slot id
-                    # would DUPLICATE that document in the result. -1 is
-                    # the dead-filler sentinel end-to-end (Retriever
-                    # translates it to page id -1; a later stage scores it
-                    # NEG in every segment since it is in-segment nowhere).
-                    parts_v.append(jax.lax.all_gather(s, axes, axis=1,
-                                                      tiled=True))
-                    gi = jnp.where(ok, jnp.take_along_axis(cand, order,
-                                                           axis=1), -1)
-                    parts_i.append(jax.lax.all_gather(gi, axes, axis=1,
-                                                      tiled=True))
-                scores, cand = merge_topk(
-                    jnp.concatenate(parts_v, axis=1),
-                    jnp.concatenate(parts_i, axis=1),
-                    min(stage.k, L))
+                    scores, cand = merge_topk(
+                        jnp.concatenate(parts_v, axis=1),
+                        jnp.concatenate(parts_i, axis=1),
+                        min(stage.k, total_cap))
+                else:
+                    L = cand.shape[1]
+                    cap_slots = min(L, max(1, -(-L // n_shards))
+                                    * rerank_overcommit)
+                    parts_v, parts_i = [], []
+                    for store, eff, cap, off in zip(stores, effs, capacities,
+                                                    offsets):
+                        n_local = cap // n_shards
+                        local = cand - off
+                        in_seg = (local >= 0) & (local < cap)
+                        lclip = jnp.clip(local, 0, cap - 1)
+                        mine = in_seg & (lclip // n_local == shard_idx)
+                        order = jnp.argsort(~mine, axis=1)[:, :cap_slots]
+                        rows = jnp.take_along_axis(lclip % n_local, order,
+                                                   axis=1)
+                        ok = jnp.take_along_axis(mine, order, axis=1)
+                        if eff is not None:
+                            ok = ok & jnp.take(eff, rows, axis=0)
+                        s = _score_candidates(
+                            *rerank_arrays(store, stage.vector),
+                            q, q_mask, rows, ok, *rerank_dispatch(stage))
+                        # merge shards/segments: each candidate scored real on
+                        # exactly one (shard, segment); NEG everywhere else.
+                        # Non-owned copies also DROP their slot id (-1): when
+                        # k exceeds the live candidates, NEG filler wins top-k
+                        # slots, and a filler copy carrying a live slot id
+                        # would DUPLICATE that document in the result. -1 is
+                        # the dead-filler sentinel end-to-end (Retriever
+                        # translates it to page id -1; a later stage scores it
+                        # NEG in every segment since it is in-segment nowhere).
+                        parts_v.append(jax.lax.all_gather(s, axes, axis=1,
+                                                          tiled=True))
+                        gi = jnp.where(ok, jnp.take_along_axis(cand, order,
+                                                               axis=1), -1)
+                        parts_i.append(jax.lax.all_gather(gi, axes, axis=1,
+                                                          tiled=True))
+                    scores, cand = merge_topk(
+                        jnp.concatenate(parts_v, axis=1),
+                        jnp.concatenate(parts_i, axis=1),
+                        min(stage.k, L))
         return scores, cand
 
     def searcher(stores, q, q_mask, fspec):
@@ -582,9 +591,11 @@ def make_segment_scan_fn(stages: tuple, capacity: int):
 
     def seg_scan(store, q, q_mask, fspec, offset):
         record_trace()
-        eff = effective_validity(store, fspec)
-        return _segment_stage0(stage, store, eff, capacity, offset,
-                               q, q_mask, **r0)
+        with jax.named_scope(SCOPE_MASK):
+            eff = effective_validity(store, fspec)
+        with jax.named_scope(SCOPE_SCAN):
+            return _segment_stage0(stage, store, eff, capacity, offset,
+                                   q, q_mask, **r0)
 
     jfn = jax.jit(seg_scan)
 
@@ -615,9 +626,11 @@ def make_segment_rerank_fn(stages: tuple, stage_index: int, capacity: int):
 
     def seg_rerank(store, q, q_mask, fspec, offset, cand):
         record_trace()
-        eff = effective_validity(store, fspec)
-        return _segment_rerank(stage, store, eff, capacity, offset,
-                               q, q_mask, cand, rr_impl, rr_interpret)
+        with jax.named_scope(SCOPE_MASK):
+            eff = effective_validity(store, fspec)
+        with jax.named_scope(SCOPE_RERANK):
+            return _segment_rerank(stage, store, eff, capacity, offset,
+                                   q, q_mask, cand, rr_impl, rr_interpret)
 
     jfn = jax.jit(seg_rerank)
 

@@ -51,7 +51,9 @@ from collections import OrderedDict, deque
 
 import numpy as np
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
+from repro.retrieval import tracing
 from repro.retrieval.engine import NEG
 
 
@@ -88,14 +90,23 @@ class PendingResult:
     it (or sheds/fails it — a completed handle always resolves: check
     ``error``/``shed``/``degraded``, or call ``result()`` to get
     ``(scores, ids)``-or-raise). ``latency`` is seconds from admission to
-    completion."""
-    __slots__ = ("scores", "ids", "t_submit", "t_done", "cached",
-                 "error", "shed", "degraded", "deadline")
+    completion.
+
+    ``t_dispatch`` is the frontend clock when the request's cohort began
+    its dispatch, and ``dispatch`` that dispatch's sequence number (the
+    argument of its ``frontend.flush`` profiler span); both stay None for
+    a shed request or a cache hit. Queue wait is ``t_dispatch -
+    t_submit``, service time ``t_done - t_dispatch``."""
+    __slots__ = ("scores", "ids", "t_submit", "t_dispatch", "dispatch",
+                 "t_done", "cached", "error", "shed", "degraded",
+                 "deadline")
 
     def __init__(self, t_submit: float, deadline: float | None = None):
         self.scores = None
         self.ids = None
         self.t_submit = t_submit
+        self.t_dispatch = None
+        self.dispatch = None
         self.t_done = None
         self.cached = False
         self.error = None
@@ -228,13 +239,15 @@ class ServingFrontend:
         hit = self._cache_get(q, qm, fkey)
         if hit is not None:
             return hit
-        scores, ids, degraded = self._run_block([(q, qm)], fkey)
-        if degraded:
-            self.stats["degraded"] += 1
-        else:
-            # a degraded (partial) answer must never be served again
-            # from cache as if it were the exact one
-            self._cache_put(q, qm, fkey, (scores, ids))
+        with TraceAnnotation(tracing.FLUSH,
+                             dispatch=self.stats["dispatches"] + 1):
+            scores, ids, degraded = self._run_block([(q, qm)], fkey)
+            if degraded:
+                self.stats["degraded"] += 1
+            else:
+                # a degraded (partial) answer must never be served again
+                # from cache as if it were the exact one
+                self._cache_put(q, qm, fkey, (scores, ids))
         return scores, ids
 
     # ------------------------------------------------------------------
@@ -364,6 +377,18 @@ class ServingFrontend:
                 live.append(item)
         if not live:
             return len(take)
+        # the number _dispatch will give this cohort's dispatch
+        seq = self.stats["dispatches"] + 1
+        with TraceAnnotation(tracing.FLUSH, dispatch=seq):
+            self._serve(live, fkey, now, seq)
+        return len(take)
+
+    def _serve(self, live: list, fkey, now: float, seq: int) -> None:
+        """Dispatch one live cohort as dispatch number ``seq`` and
+        complete every member, with its answer or the dispatch error."""
+        t_dispatch = self.clock()
+        for pr, _, _ in live:
+            pr.t_dispatch, pr.dispatch = t_dispatch, seq
         budget = None
         deadlines = [pr.deadline for pr, _, _ in live
                      if pr.deadline is not None]
@@ -388,7 +413,7 @@ class ServingFrontend:
                 # must still reach the serving loop — complete the
                 # cohort, then let it fly
                 raise
-            return len(take)
+            return
         r0 = 0
         t_done = self.clock()
         for pr, q, qm in live:
@@ -402,7 +427,6 @@ class ServingFrontend:
                 # degraded (partial) answers are flagged, never cached
                 self._cache_put(q, qm, fkey, (pr.scores, pr.ids))
             r0 += b
-        return len(take)
 
     def _shed(self, pr: PendingResult, now: float) -> None:
         pr.shed = True
@@ -466,14 +490,15 @@ class ServingFrontend:
         q_len = max(q.shape[1] for q, _ in reqs)
         d = reqs[0][0].shape[2]
         bb, qb = self.bucket_for(rows, q_len)
-        qp = np.zeros((bb, qb, d), np.float32)
-        qmp = np.zeros((bb, qb), bool)
-        r0 = 0
-        for q, qm in reqs:
-            b, ql, _ = q.shape
-            qp[r0:r0 + b, :ql] = q
-            qmp[r0:r0 + b, :ql] = qm
-            r0 += b
+        with TraceAnnotation(tracing.PAD):
+            qp = np.zeros((bb, qb, d), np.float32)
+            qmp = np.zeros((bb, qb), bool)
+            r0 = 0
+            for q, qm in reqs:
+                b, ql, _ = q.shape
+                qp[r0:r0 + b, :ql] = q
+                qmp[r0:r0 + b, :ql] = qm
+                r0 += b
         return self._dispatch(qp, qmp, rows=rows, fkey=fkey,
                               deadline_ms=deadline_ms)
 
@@ -493,22 +518,29 @@ class ServingFrontend:
             # tiered path: the engine translates/masks ids itself and
             # degrades under the cohort's remaining budget instead of
             # blocking on cold-segment promotions
-            res = self._engine.search(
+            with TraceAnnotation(tracing.LAUNCH):
+                res = self._engine.search(
+                    jnp.asarray(qp), jnp.asarray(qmp), stages=self.stages,
+                    filter=fkey, deadline_ms=deadline_ms,
+                    degrade=self._degrade)
+            with TraceAnnotation(tracing.SYNC):
+                return (np.asarray(res.scores)[:rows],
+                        np.asarray(res.ids)[:rows], bool(res.degraded))
+        with TraceAnnotation(tracing.LAUNCH):
+            scores, slots = self.retriever.search(
                 jnp.asarray(qp), jnp.asarray(qmp), stages=self.stages,
-                filter=fkey, deadline_ms=deadline_ms,
-                degrade=self._degrade)
-            return (np.asarray(res.scores)[:rows],
-                    np.asarray(res.ids)[:rows], bool(res.degraded))
-        scores, slots = self.retriever.search(
-            jnp.asarray(qp), jnp.asarray(qmp), stages=self.stages,
-            translate_ids=False, filter=fkey)
-        scores = np.asarray(scores)[:rows]
-        slots = np.asarray(slots)[:rows]
-        ids = self.retriever.store.translate_slots(slots)
-        # filter-excluded live slots score NEG like dead slots; mask their
-        # ids so filler can never expose another tenant's page ids (same
-        # contract as Retriever.search with translate_ids=True)
-        return scores, np.where(scores <= NEG / 2, np.int64(-1), ids), False
+                translate_ids=False, filter=fkey)
+        with TraceAnnotation(tracing.SYNC):
+            scores = np.asarray(scores)[:rows]
+            slots = np.asarray(slots)[:rows]
+        with TraceAnnotation(tracing.TRANSLATE):
+            ids = self.retriever.store.translate_slots(slots)
+            # filter-excluded live slots score NEG like dead slots; mask
+            # their ids so filler can never expose another tenant's page
+            # ids (same contract as Retriever.search with
+            # translate_ids=True)
+            ids = np.where(scores <= NEG / 2, np.int64(-1), ids)
+        return scores, ids, False
 
     def _cache_key(self, q: np.ndarray, qm: np.ndarray, fkey):
         # the store generation invalidates every entry on corpus mutation

@@ -1,4 +1,5 @@
-"""Trace-count hook for the no-retrace contract.
+"""Trace-count hook for the no-retrace contract, and the names of the
+serving path's profiler spans.
 
 Every repro-owned jitted function on the serving mutation/search/ingest
 path calls ``record_trace()`` from inside its traced body. The call is a
@@ -36,6 +37,39 @@ The static counterpart to this runtime counter is the contract auditor
 (``python -m repro.analysis --check``): its R1 rule proves every serving
 jit body actually calls ``record_trace()``, so a forgotten hook can't
 make this counter silently blind.
+
+Profiler spans. The serving path marks its own work on the profiler's
+clock, the clock the device trace uses, so a trace taken with
+``jax.profiler.trace`` can say which host step each device gap waits
+for and which cascade stage owns each device op. The names are the
+constants below, defined here once:
+
+- ``frontend.flush`` (``FLUSH``): one cohort's dispatch through
+  ``ServingFrontend``, from stamping its members to scattering their
+  answers (a ``flush``, or one direct ``search``). It carries the
+  dispatch's sequence number, ``stats["dispatches"]`` after the
+  increment, as the argument ``dispatch``; the same number is stamped
+  on each member's ``PendingResult.dispatch``. The four spans below
+  nest inside it, in this order:
+- ``frontend.pad`` (``PAD``): padding the cohort into its bucket block
+  in NumPy;
+- ``frontend.launch`` (``LAUNCH``): the host-to-device copies of the
+  block and the filter triple, and the call into the compiled cascade
+  up to its return (the call is asynchronous);
+- ``frontend.sync`` (``SYNC``): the blocking fetch of scores and slot
+  ids to the host, which waits for the device to finish;
+- ``frontend.translate`` (``TRANSLATE``): slot ids to page ids and the
+  masking of filler ids (on the tiered engine's path the engine
+  translates inside ``frontend.launch``, and this span is absent).
+
+Device ops carry the cascade stage that emitted them as a
+``jax.named_scope`` in their op metadata (``cascade.mask`` for the
+effective-validity masks, ``cascade.scan`` for stage 0 with its merge,
+``cascade.rerank`` for every later stage with its top-k and take). A
+scope changes op metadata only: no computation, fusion or kernel name.
+
+With no profiler running a span costs one inactive ``TraceMe``; no
+string is built on the hot path.
 """
 from __future__ import annotations
 
@@ -47,6 +81,17 @@ _LOCK = threading.Lock()
 _TRACES = [0]
 _TRACE_LOG: list = []        # qualified name per record_trace() call
 _TRACE_LOG_MAX = 256         # bound the log; the count stays exact
+
+# host spans of ServingFrontend, one set per dispatch (module docstring)
+FLUSH = "frontend.flush"
+PAD = "frontend.pad"
+LAUNCH = "frontend.launch"
+SYNC = "frontend.sync"
+TRANSLATE = "frontend.translate"
+# named scopes of the compiled cascade's stages
+SCOPE_MASK = "cascade.mask"
+SCOPE_SCAN = "cascade.scan"
+SCOPE_RERANK = "cascade.rerank"
 
 
 def record_trace(name: str | None = None) -> None:
