@@ -63,8 +63,9 @@ def maxsim_scores(q: jax.Array, docs: jax.Array,
                   interpret: bool = True) -> jax.Array:
     """q [B,Q,d], docs [N,D,d] -> scores [B,N] (f32).
 
-    ``block_n`` documents stream per kernel grid step (0 = sized by
-    ``maxsim.doc_block`` to the VMEM tile budget). ``doc_valid`` [N] bool
+    ``block_n`` documents stream per kernel grid step, as
+    ``maxsim.doc_block`` fits it to the kernel body (0 = its default
+    tile). ``doc_valid`` [N] bool
     marks live documents in a capacity-padded store; dead slots score NEG
     so they can never enter a top-k on merit. The mask is applied to the
     kernel OUTPUT — the kernel still streams the full padded corpus
@@ -87,7 +88,7 @@ def maxsim_scores(q: jax.Array, docs: jax.Array,
         return out
 
     # pad Q to the sublane tile, B to the query block, N to block_n
-    bn = block_n or doc_block(D, d, docs.dtype.itemsize, N)
+    bn = doc_block(D, d, docs.dtype.itemsize, N, block_n)
     qp = _pad_to(q, 1, 8)
     qmp = _pad_to(q_mask, 1, 8)
     bq = query_block(B, qp.shape[1])
@@ -349,9 +350,10 @@ def centroid_scores(q: jax.Array, centroids: jax.Array,
     qmp = _pad_to(q_mask, 1, 8)
     bq = query_block(B, qp.shape[1])
     qp, qmp = _pad_to(qp, 0, bq), _pad_to(qmp, 0, bq)
-    docs_p = _pad_to(centroids[:, None, :].astype(jnp.float32), 0, 8)
+    bn = doc_block(1, dc, 4, K)
+    docs_p = _pad_to(centroids[:, None, :].astype(jnp.float32), 0, bn)
     dm_p = jnp.ones((docs_p.shape[0], 1), jnp.float32)
-    out = maxsim_pallas(qp, qmp, docs_p, dm_p, block_n=8,
+    out = maxsim_pallas(qp, qmp, docs_p, dm_p, block_n=bn,
                         interpret=interpret)
     return out[:B, :K]
 

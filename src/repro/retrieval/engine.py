@@ -8,8 +8,9 @@ API call", pod-scale edition). Design rules:
   ("rerank where the data lives");
 - the only interconnect traffic is (score, id) pairs: S*B*K*8 bytes per
   stage via all-gather — independent of D and d;
-- stage-1 full-corpus scan is the memory-roofline term (N_local * D' * d
-  bytes); pooling shrinks it 32-64x, int8 storage halves it again;
+- stage-1 full-corpus scan is the largest term (N_local * D' * d bytes,
+  2 * B * Q * N_local * D' * d operations); pooling shrinks it 32-64x,
+  int8 storage halves its bytes again;
 - later stages score only each shard's members of the global candidate set,
   compacted to a fixed per-shard cap (exact when cap >= per-shard hits;
   cap defaults to 8x the fair share);

@@ -17,20 +17,48 @@ from repro.configs import get_config
 # MaxSim kernel
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("B,Q,N,D,d", [
+# f32 docs at block_n=8 and odd shapes: the padding paths of both bodies
+_ODD_SHAPES = [
     (1, 8, 8, 32, 128),
     (3, 10, 24, 96, 128),
     (2, 17, 40, 64, 64),      # Q not sublane-aligned -> padding path
-    (4, 32, 16, 130, 128),    # D not sublane-aligned
-])
-def test_maxsim_shapes(rng, B, Q, N, D, d):
+    (4, 32, 16, 130, 128),    # D not sublane-aligned, per-page body
+]
+# the served pooled geometries (colpali D'=34, colsmol D'=13, D=1) in the
+# served doc dtypes at the default block_n: (1,16) spans three page tiles,
+# (64,32) several query blocks
+_SERVED_SHAPES = [(B, Q, N, D, 128, dtype, 0)
+                  for D in (34, 13, 1) for dtype in ("bfloat16", "int8")
+                  for B, Q, N in ((1, 16, 1100), (16, 32, 200),
+                                  (64, 32, 200))]
+
+
+@pytest.mark.parametrize("B,Q,N,D,d,dtype,block_n", [
+    pytest.param(*shape, "float32", 8, id="-".join(map(str, shape)))
+    for shape in _ODD_SHAPES] + [
+    pytest.param(*case, id="{5}-D{3}-B{0}-Q{1}-N{2}".format(*case))
+    for case in _SERVED_SHAPES])
+def test_maxsim_shapes(rng, B, Q, N, D, d, dtype, block_n):
+    """Kernel vs the f32 oracle with masked tokens and one fully masked
+    page. Docs exact in bf16 (bf16, int8 codes) hold 1e-5: the products
+    stay exact to f32."""
     q = jnp.asarray(rng.normal(size=(B, Q, d)), jnp.float32)
     docs = jnp.asarray(rng.normal(size=(N, D, d)), jnp.float32)
     qm = jnp.asarray(rng.random((B, Q)) > 0.2, jnp.float32)
-    dm = jnp.asarray(rng.random((N, D)) > 0.1, jnp.float32)
-    out = maxsim_scores(q, docs, qm, dm, impl="pallas", block_n=8)
-    ref = maxsim_ref(q, qm, docs, dm)
-    np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4)
+    dm = jnp.asarray(rng.random((N, D)) > 0.1, jnp.float32).at[3].set(0.0)
+    scales = None
+    if dtype != "float32":    # unit vectors, as the encoders emit them
+        q = q / jnp.linalg.norm(q, axis=-1, keepdims=True)
+        docs = docs / jnp.linalg.norm(docs, axis=-1, keepdims=True)
+    if dtype == "int8":
+        docs, scales = quantize_int8(docs)
+    else:
+        docs = docs.astype(dtype)
+    out = maxsim_scores(q, docs, qm, dm, scales, impl="pallas",
+                        block_n=block_n)
+    ref = maxsim_ref(q, qm, docs, dm, scales)
+    tol = 1e-4 if dtype == "float32" else 1e-5
+    np.testing.assert_allclose(out, ref, rtol=tol, atol=tol)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
