@@ -6,7 +6,10 @@ pages made on the device from the seed, ingested through
 into a ``Retriever``, and served by a ``ServingFrontend`` over the
 kernel-routed two-stage cascade (Pallas scan over the pooled vectors,
 Pallas gather-rerank over the full-resolution ones). It warms the buckets
-the cell's mix names and no others.
+the cell's mix names and no others. A cell of more than one chip is the
+deployment doc-sharded over the first ``chips`` devices: the store is laid
+out on a one-axis mesh through the program's own ``mesh=``, and the
+configuration's ``pages`` is the whole corpus over those chips.
 
 The window drives that frontend with the mix's generator for ``seconds``.
 Afterwards the metric readers (``bench/metrics``) turn what the window
@@ -81,6 +84,23 @@ class Run:
         return np.asarray(out, float)
 
 
+def doc_mesh(devices: list):
+    """A one-axis mesh over ``devices`` to doc-shard the store on; None for
+    one device, where a mesh would put the program on its ``shard_map``
+    body instead of the one-chip path a deployment of one chip runs."""
+    if len(devices) == 1:
+        return None
+    return jax.make_mesh((len(devices),), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,),
+                         devices=devices)
+
+
+def fullest(devices: list, key: str) -> int:
+    """The largest ``memory_stats()[key]`` over ``devices``: the fullest
+    chip's reading (0 where the backend keeps no statistics)."""
+    return max(int((d.memory_stats() or {}).get(key, 0)) for d in devices)
+
+
 class Served:
     """The system under test for one seed: retriever, frontend, queries."""
 
@@ -109,8 +129,9 @@ class Served:
         topic_vecs = corpus.topics(seed, cfg["topics"], geo["dim"])
         n, batch = cfg["pages"], cfg["ingest_batch"]
         first = corpus.page_batch(geo, seed, 0, topic_vecs, batch)
+        self.devices = jax.devices()[:cell.chips]    # the cell's chips
         self.retriever = Retriever(pipe.index(first, tt), capacity=n,
-                                   ingest=pipe)
+                                   ingest=pipe, mesh=doc_mesh(self.devices))
         del first
         for b in range(1, n // batch):
             self.retriever.ingest(
@@ -253,9 +274,9 @@ def run_cell(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
     log(f"set-up: {served.retriever.n_docs} pages, {n_warm} buckets warmed")
 
     gen = manifest.generator(cell.traffic)
-    # what the served state holds on the device, before the window
-    resident = int((jax.devices()[0].memory_stats() or {})
-                   .get("bytes_in_use", 0))
+    devices = served.devices
+    # what the served state holds on the fullest chip, before the window
+    resident = fullest(devices, "bytes_in_use")
     before = dict(fe.stats)
     traces0 = tracing.trace_count()
     if trace:
@@ -278,10 +299,9 @@ def run_cell(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
     window = requests
 
     dev = jax.devices()
-    mem = dev[0].memory_stats() or {}
     device = {"platform": dev[0].platform, "kind": dev[0].device_kind,
               "count": len(dev),
-              "memory_peak_bytes": int(mem.get("peak_bytes_in_use", 0)),
+              "memory_peak_bytes": fullest(devices, "peak_bytes_in_use"),
               "memory_window_start_bytes": resident}
     shapes = _shapes(cell.config, served.retriever)
     run = Run(cell, setup_s, t0, t1, window,
@@ -311,7 +331,8 @@ def run_cell(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
     queries, lens = served.queries, served.lens
     del served, fe, gen
     gc.collect()
-    checks = check(cell.config, seed, sample, queries, lens)
+    checks = check(cell.config, seed, sample, queries, lens,
+                   devices if len(devices) > 1 else None)
     checks["unanswered"] = (failed, 0)
     checks["retraces"] = (retraces, 0)
     checks["routing"] = (routing_faults, 0)
@@ -339,9 +360,10 @@ def _shapes(cfg: dict, retriever) -> dict:
             "prefetch_k": cfg["cascade"]["prefetch_k"]}
 
 
-def check(cfg: dict, seed: int, sample: list, queries, lens) -> dict:
-    """Compare the sampled answers with the plain reference: {name:
-    (value, limit)}."""
+def check(cfg: dict, seed: int, sample: list, queries, lens,
+          devices=None) -> dict:
+    """Compare the sampled answers with the plain reference, doc-sharded
+    over ``devices`` when given: {name: (value, limit)}."""
     limits = cfg["check"]["limits"]
     if not sample:
         return {"answers": (1, 0)}
@@ -350,7 +372,7 @@ def check(cfg: dict, seed: int, sample: list, queries, lens) -> dict:
     scores = np.concatenate([r.handle.scores for r in sample])
     ids = np.concatenate([r.handle.ids for r in sample])
     t = time.perf_counter()
-    ref = reference.Reference(cfg, seed)
+    ref = reference.Reference(cfg, seed, devices)
     res = ref.search(q, ln, cfg["cascade"]["prefetch_k"], ids)
     del ref
     nums = reference.compare(res, scores, ids, cfg["pages"],

@@ -1,7 +1,8 @@
 """cascade_roofline: the whole served cascade against its roofline: the
 least time of both stages' work (``scan_roofline.work`` and
 ``rerank_roofline.work``) summed over every dispatch answered in the
-window, over the device's busy time in the window (profiler trace). It
+window, at one chip's peaks, over the device's busy time in the window
+summed over the chips that ran (profiler trace). It
 reads the same work whatever implements a stage, so it still bounds a
 claim after a kernel is fused, replaced or taken off the path."""
 from bench import manifest
@@ -15,4 +16,4 @@ def read(run):
         return None
     total = sum(_scan.least(run, B, Q)[0] + _rerank.least(run, B, Q)[0]
                 for B, Q in run.buckets)
-    return 100.0 * total / run.trace.busy_s
+    return 100.0 * total / run.trace.chip_busy_s

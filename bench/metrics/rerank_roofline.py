@@ -4,7 +4,10 @@
 The work is what exact reranking needs for a dispatched (B, Q) bucket:
 each query's ``prefetch_k`` candidate pages read once at full resolution,
 and one multiply-add per (query token, page vector, candidate,
-coordinate). Least time and share as in ``scan_roofline``."""
+coordinate). Least time and share as in ``scan_roofline``: on a
+doc-sharded store each chip reranks its members of the candidate set,
+padded to a fixed number of rows, and the share counts the filler rows as
+time spent on no work."""
 import re
 
 from bench import manifest
@@ -36,7 +39,7 @@ def read(run):
     per = [least(run, B, Q) for B, Q in run.buckets]
     mean = sum(t for t, _ in per) / len(per)
     bounds = sorted({b for _, b in per})
-    run.note(f"rerank_roofline: {calls} kernel calls, {secs:.6f}s on "
-             f"device, least {mean * 1e3:.4f} ms per call, "
+    run.note(f"rerank_roofline: {calls:g} kernel dispatches, {secs:.6f} "
+             f"chip-seconds, least {mean * 1e3:.4f} ms per call, "
              f"{'/'.join(bounds)}-bound")
     return 100.0 * mean * calls / secs

@@ -5,8 +5,12 @@ The work is what the algorithm needs for a dispatched (B, Q) bucket, not
 what one implementation moves: the pooled corpus read once, and one
 multiply-add per (query token, pooled vector, page, coordinate). The
 least time is the larger of bytes / HBM bandwidth and operations / peak
-(the peak of the stored dtype); the share is that least time, summed
-over the kernel's calls, over the kernel's device time."""
+(the peak of the stored dtype), at one chip's peaks; the share is that
+least time, summed over the kernel's dispatches, over the kernel's device
+time summed over chips. A dispatch of a doc-sharded store runs the kernel
+once on every chip, each over its share of the corpus: it counts once,
+and its chip-seconds are what it spent on all of them, so an evenly
+split scan reads what one chip doing all of it would."""
 import re
 
 # the op name of the kernel's custom call in the compiled cascade (the
@@ -35,7 +39,8 @@ def least(run, B: int, Q: int) -> tuple:
 
 
 def kernel_seconds(run, kernel, family: str) -> tuple:
-    """(device seconds, calls) of the ops ``kernel`` matches. Nothing
+    """(device seconds summed over chips, dispatches) of the ops
+    ``kernel`` matches (``trace_reduce.Summary.op_seconds``). Nothing
     matching is an error when the dispatch registry shows the family ran
     its Pallas kernel in this run: the op's name has changed under the
     pattern, and the metric would vanish unseen."""
@@ -57,6 +62,7 @@ def read(run):
     per = [least(run, B, Q) for B, Q in run.buckets]
     mean = sum(t for t, _ in per) / len(per)
     bounds = sorted({b for _, b in per})
-    run.note(f"scan_roofline: {calls} kernel calls, {secs:.6f}s on device, "
+    run.note(f"scan_roofline: {calls:g} kernel dispatches, {secs:.6f} "
+             f"chip-seconds, "
              f"least {mean * 1e3:.4f} ms per call, {'/'.join(bounds)}-bound")
     return 100.0 * mean * calls / secs

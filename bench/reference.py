@@ -29,6 +29,7 @@ pooled scores differ by rounding) cannot fail a sound run:
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -116,30 +117,78 @@ def _exact(q, qm, full, keep, ids):
     return _sum_best(jnp.where(keep[ids][:, :, None, :], sim, -jnp.inf), qm)
 
 
-class Reference:
-    """The regenerated corpus, indexed by the reference, on the device:
-    pooled and full-resolution vectors of every page, in page-id order
-    (the order the program ingested them, batch by batch)."""
+def _on(device):
+    """Place what follows on ``device``; the default device for None."""
+    return contextlib.nullcontext() if device is None \
+        else jax.default_device(device)
 
-    def __init__(self, cfg: dict, seed: int):
+
+def _merge_top(parts: list, per: int, k: int) -> tuple:
+    """The best ``k`` of the shards' own top lists ``[(scores [B, k], ids
+    [B, k])]``, shard ``s``'s ids offset by ``s * per``: (scores, ids) in
+    falling score order, a tie to the lower id, as ``lax.top_k`` orders
+    them over the whole corpus. One shard's list is already that."""
+    if len(parts) == 1:
+        return parts[0]
+    sc = np.concatenate([p[0] for p in parts], axis=1)
+    gi = np.concatenate([p[1] + s * per for s, p in enumerate(parts)],
+                        axis=1)
+    order = np.lexsort((gi, -sc))[:, :k]
+    return (np.take_along_axis(sc, order, axis=1),
+            np.take_along_axis(gi, order, axis=1))
+
+
+class Reference:
+    """The regenerated corpus, indexed by the reference: pooled and
+    full-resolution vectors of every page, in page-id order (the order the
+    program ingested them, batch by batch).
+
+    On the default device, or doc-sharded over ``devices``: device ``s``
+    holds pages ``[s * per, (s + 1) * per)`` and computes their scores, so
+    that no device holds more than its share of the corpus and one query
+    block's working set. The shards' top lists meet on the host. Every
+    number ``search`` returns is the one-device reference's, bit for bit:
+    each page is indexed and scored by the same programs at the same
+    shapes (the scan's ``chunk`` pages a block permitting), only on
+    another device."""
+
+    def __init__(self, cfg: dict, seed: int, devices=None):
         geo = cfg["geometry"]
         n, batch = cfg["pages"], cfg["ingest_batch"]
+        self.devices = list(devices) if devices else [None]
+        per = n // len(self.devices)
+        if per * len(self.devices) != n:
+            raise ValueError(f"{n} pages do not split evenly over "
+                             f"{len(self.devices)} devices")
         topic_vecs = corpus.topics(seed, cfg["topics"], geo["dim"])
-        group, window = (jnp.asarray(m) for m in _pool_matrices(geo))
         dt = cfg["store_dtype"]
-        n_pooled = window.shape[0]
-        self.pooled = jnp.zeros((n, n_pooled, geo["dim"]), dt)
-        self.full = jnp.zeros((n, geo["n_patches"], geo["dim"]), dt)
-        self.keep = jnp.zeros((n, geo["n_patches"]), bool)
-        for b in range(n // batch):
-            raw = corpus.page_batch(geo, seed, b, topic_vecs, batch)
-            pooled, full, keep = _index(raw, group, window,
-                                        n_special=geo["n_special"], dtype=dt)
-            start = jnp.int32(b * batch)
-            self.pooled = _put(self.pooled, pooled, start)
-            self.full = _put(self.full, full, start)
-            self.keep = _put(self.keep, keep, start)
-        self.n = n
+        self.pooled, self.full, self.keep = [], [], []
+        for s, dev in enumerate(self.devices):
+            lo, hi = s * per, (s + 1) * per
+            with _on(dev):
+                group, window = (jnp.asarray(m) for m in _pool_matrices(geo))
+                pooled = jnp.zeros((per, window.shape[0], geo["dim"]), dt)
+                full = jnp.zeros((per, geo["n_patches"], geo["dim"]), dt)
+                keep = jnp.zeros((per, geo["n_patches"]), bool)
+                for b in range(lo // batch, -(-hi // batch)):
+                    raw = corpus.page_batch(geo, seed, b, topic_vecs, batch)
+                    idx = _index(raw, group, window,
+                                 n_special=geo["n_special"], dtype=dt)
+                    a, z = max(b * batch, lo), min((b + 1) * batch, hi)
+                    if z - a < batch:     # a batch over two shards
+                        idx = tuple(x[a - b * batch:z - b * batch]
+                                    for x in idx)
+                    start = jnp.int32(a - lo)
+                    pooled = _put(pooled, idx[0], start)
+                    full = _put(full, idx[1], start)
+                    keep = _put(keep, idx[2], start)
+            if dev is not None:
+                # committed: a program given a shard runs on its device
+                pooled, full, keep = jax.device_put((pooled, full, keep), dev)
+            self.pooled.append(pooled)
+            self.full.append(full)
+            self.keep.append(keep)
+        self.n, self.per = n, per
 
     def search(self, q: np.ndarray, lens: np.ndarray, prefetch_k: int,
                extra_ids: np.ndarray, block: int = 8,
@@ -155,25 +204,51 @@ class Reference:
         qm = np.concatenate([qm, np.zeros((pad, qm.shape[1]), bool)])
         extra = np.concatenate([extra_ids, np.zeros(
             (pad, extra_ids.shape[1]), extra_ids.dtype)])
-        chunk = min(chunk, self.n)
-        while self.n % chunk:
+        chunk = min(chunk, self.per)
+        while self.per % chunk:
             chunk //= 2
         out = {"pooled_top": [], "cand": [], "exact": [], "pooled_extra": []}
         with jax.default_matmul_precision("highest"):
             for i in range(0, len(q), block):
-                qb = jnp.asarray(q[i:i + block])
-                mb = jnp.asarray(qm[i:i + block])
-                s = _scan(qb, mb, self.pooled, chunk=chunk)
-                top_s, top_i = jax.lax.top_k(s, prefetch_k + 1)
-                ex = jnp.clip(jnp.asarray(extra[i:i + block]), 0, self.n - 1)
-                ids = jnp.concatenate([top_i[:, :prefetch_k], ex], axis=1)
-                out["pooled_top"].append(np.asarray(top_s))
-                out["cand"].append(np.asarray(top_i[:, :prefetch_k]))
-                out["exact"].append(np.asarray(
-                    _exact(qb, mb, self.full, self.keep, ids)))
-                out["pooled_extra"].append(np.asarray(
-                    jnp.take_along_axis(s, ex, axis=1)))
+                ex = np.clip(extra[i:i + block], 0, self.n - 1)
+                tops, taken, blocks = [], [], []
+                for s, dev in enumerate(self.devices):
+                    with _on(dev):
+                        qb = jnp.asarray(q[i:i + block])
+                        mb = jnp.asarray(qm[i:i + block])
+                        sc = _scan(qb, mb, self.pooled[s], chunk=chunk)
+                        tops.append(jax.lax.top_k(
+                            sc, min(prefetch_k + 1, self.per)))
+                        taken.append(jnp.take_along_axis(
+                            sc, jnp.asarray(np.clip(ex - s * self.per, 0,
+                                                    self.per - 1)), axis=1))
+                    blocks.append((qb, mb))
+                top_s, top_i = _merge_top(
+                    [tuple(np.array(a) for a in t) for t in tops],
+                    self.per, prefetch_k + 1)
+                ids = np.concatenate([top_i[:, :prefetch_k], ex], axis=1)
+                exact = [_exact(qb, mb, self.full[s], self.keep[s],
+                                jnp.asarray(np.clip(ids - s * self.per, 0,
+                                                    self.per - 1)))
+                         for s, (qb, mb) in enumerate(blocks)]
+                owner = ids // self.per
+                owner_ex = ex // self.per
+                out["pooled_top"].append(top_s)
+                out["cand"].append(top_i[:, :prefetch_k])
+                out["exact"].append(_pick(exact, owner))
+                out["pooled_extra"].append(_pick(taken, owner_ex))
         return {k: np.concatenate(v)[:S] for k, v in out.items()}
+
+
+def _pick(per_shard: list, owner: np.ndarray) -> np.ndarray:
+    """Each entry from the shard that owns its page: ``per_shard[s]`` holds
+    every entry as shard ``s`` computed it, right only where ``owner`` is
+    ``s``. Copied to the host, so no device buffer outlives its block."""
+    got = [np.array(a) for a in per_shard]
+    out = got[0]
+    for s, a in enumerate(got[1:], start=1):
+        out = np.where(owner == s, a, out)
+    return out
 
 
 def compare(ref: dict, scores: np.ndarray, ids: np.ndarray, n_docs: int,

@@ -91,3 +91,24 @@ def test_load_reads_host_spans_of_a_recorded_trace(tmp_path):
     assert t["devices"] == []                 # no TPU plane on this host
     s = TR.summarize(t)
     assert s.busy_s == 0 and s.window_s > 0
+
+
+def test_an_op_on_every_chip_counts_one_dispatch():
+    # one dispatch of a doc-sharded program runs its ops on every chip
+    lanes = [[("scan", 10 * MS, 20 * MS), ("rerank", 40 * MS, 10 * MS)]
+             for _ in range(4)]
+    s = TR.summarize({"devices": lanes, "host": [("bench.window", 0,
+                                                  100 * MS)]})
+    assert s.chips == 4
+    assert s.op_n == {"scan": 4, "rerank": 4}
+    assert s.op_lanes == {"scan": 4, "rerank": 4}
+    assert s.op_seconds(lambda n: n == "scan") == (pytest.approx(0.08), 1)
+    assert s.busy_s == pytest.approx(0.03)
+    assert s.chip_busy_s == pytest.approx(0.12)
+
+
+def test_one_chip_counts_are_its_events():
+    s = TR.summarize(_trace())
+    assert s.chips == 1 and s.op_lanes == {"scan": 1, "fusion.1": 1,
+                                           "rerank": 1}
+    assert s.chip_busy_s == s.busy_s

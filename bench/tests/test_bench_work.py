@@ -80,3 +80,64 @@ def test_reader_fails_when_a_kernel_that_ran_is_not_in_the_trace(reader,
                ran=[family])
     with pytest.raises(RuntimeError, match=family):
         reader.read(run)
+
+
+MS = 1_000_000   # ns
+COLPALI_98K = dict(COLPALI, n_docs=98304, pooled=(98304, 34, 128),
+                   full=(98304, 1024, 128))
+
+
+def _traced(lanes: int, scan_ms: float, rerank_ms: float, n: int = 10,
+            shapes=COLPALI):
+    """A window of ``n`` (16, 32) dispatches, each the scan then the rerank
+    on every one of ``lanes`` chips, summarized as a run would be."""
+    period = 2 * (scan_ms + rerank_ms) * MS
+    lane = []
+    for i in range(n):
+        t = 1 * MS + i * period
+        lane += [("maxsim_scores.1", t, scan_ms * MS),
+                 ("maxsim_rerank.1", t + scan_ms * MS, rerank_ms * MS)]
+    trace = trace_reduce.summarize(
+        {"devices": [list(lane) for _ in range(lanes)],
+         "host": [(trace_reduce.WINDOW_SPAN, 0, int(n * period + 2 * MS))]})
+    return types.SimpleNamespace(trace=trace, buckets=[(16, 32)] * n,
+                                 shapes=shapes, peaks=PEAKS,
+                                 kernels_ran=frozenset(),
+                                 note=lambda msg: None)
+
+
+@pytest.mark.parametrize("reader", [scan, rerank, cascade],
+                         ids=["scan", "rerank", "cascade"])
+def test_work_split_over_four_chips_reads_as_one_chip(reader):
+    # the same dispatches split evenly over four chips' lanes, each chip
+    # taking a quarter of the time, read what one chip doing it all reads
+    one = reader.read(_traced(1, 4 * 1.77, 4 * 3.29))
+    four = reader.read(_traced(4, 1.77, 3.29))
+    assert four == pytest.approx(one, rel=1e-12)
+    assert 0 < four < 100
+
+
+def test_four_chip_scan_reads_a_chips_share_of_the_whole_corpus():
+    # 98,304 pages over four chips, each scanning its 24,576 in the
+    # one-chip kernel time: the one-chip share, not four times it
+    least_24k = scan.least(_run([], {}, {}, 0), 16, 32)[0]
+    run = _traced(4, 1.77, 3.29, shapes=COLPALI_98K)
+    assert scan.read(run) == pytest.approx(100 * least_24k / 1.77e-3,
+                                           rel=1e-9)
+    assert scan.read(run) < 35
+
+
+def test_one_chip_readings_keep_their_formula_exactly():
+    # at one chip the per-chip counts reduce to the formulas of one lane:
+    # share = mean least time * kernel events / kernel seconds, and the
+    # cascade's summed least time over busy time
+    run = _traced(1, 1.77, 3.29)
+    t = run.trace
+    for reader, name in ((scan, "maxsim_scores.1"),
+                         (rerank, "maxsim_rerank.1")):
+        mean = reader.least(run, 16, 32)[0]
+        assert reader.read(run) == 100.0 * mean * t.op_n[name] \
+            / t.op_s[name]
+    total = sum(scan.least(run, B, Q)[0] + rerank.least(run, B, Q)[0]
+                for B, Q in run.buckets)
+    assert cascade.read(run) == 100.0 * total / t.busy_s

@@ -8,11 +8,13 @@ the benchmark's own host spans (``bench.*`` ``TraceAnnotation``s).
 ``summarize`` reduces those lists inside the ``bench.window`` span:
 
 - busy: the union of the intervals in which an operation ran, per chip,
-  averaged over the chips that ran any;
-- op time: summed device durations per operation name: the HLO
-  instruction's name (``maxsim_scores.1``, ``fusion.3``), cut from the
-  instruction text that a TPU's ``XLA Ops`` line carries as the event
-  name (``%maxsim_scores.1 = f32[...] custom-call(...)``);
+  averaged over the chips that ran any (``chip_busy_s``: summed);
+- op time: summed device durations per operation name over every chip:
+  the HLO instruction's name (``maxsim_scores.1``, ``fusion.3``), cut
+  from the instruction text that a TPU's ``XLA Ops`` line carries as the
+  event name (``%maxsim_scores.1 = f32[...] custom-call(...)``), with the
+  number of chips whose lane has the op, so that one dispatch of a program
+  that runs on every chip counts once;
 - idle gaps: the complement of busy inside the window, each piece billed
   to the innermost ``bench.*`` span covering it (``host.none`` where no
   span does).
@@ -22,7 +24,7 @@ from __future__ import annotations
 import bisect
 import re
 import shutil
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from pathlib import Path
 
 WINDOW_SPAN = "bench.window"
@@ -92,12 +94,21 @@ class Summary:
     op_s: dict                    # op name -> device seconds (all chips)
     op_n: dict                    # op name -> events in the window
     idle_s: dict                  # host span -> idle device seconds
+    op_lanes: dict = field(default_factory=dict)  # op -> chips that ran it
+    chips: int = 1                # chips that ran ops
+
+    @property
+    def chip_busy_s(self) -> float:
+        """Busy time summed over the chips that ran ops."""
+        return self.busy_s * self.chips
 
     def op_seconds(self, match) -> tuple:
-        """(seconds, events) of the ops whose name ``match`` accepts."""
+        """(device seconds summed over chips, dispatches) of the ops whose
+        name ``match`` accepts: an op's events over the chips whose lane
+        has it (one for an op not counted by lane)."""
         names = [n for n in self.op_s if match(n)]
         return (sum(self.op_s[n] for n in names),
-                sum(self.op_n[n] for n in names))
+                sum(self.op_n[n] / self.op_lanes.get(n, 1) for n in names))
 
     def breakdown(self) -> dict:
         top = sorted(self.op_s.items(), key=lambda kv: -kv[1])[:TOP]
@@ -113,16 +124,19 @@ def summarize(trace: dict) -> Summary:
     lo, hi = windows[0]
     spans = _Spans([(s, s + d, n) for n, s, d in trace["host"]
                     if n != WINDOW_SPAN and _clip(s, s + d, lo, hi)])
-    op_s, op_n, busy, idle = {}, {}, [], {}
+    op_s, op_n, lanes, busy, idle = {}, {}, {}, [], {}
     for ops in trace["devices"]:
-        ivs = []
+        ivs, names = [], set()
         for name, s, d in ops:
             c = _clip(s, s + d, lo, hi)
             if c is None:
                 continue
             ivs.append(c)
+            names.add(name)
             op_s[name] = op_s.get(name, 0.0) + (c[1] - c[0]) * 1e-9
             op_n[name] = op_n.get(name, 0) + 1
+        for name in names:
+            lanes[name] = lanes.get(name, 0) + 1
         if not ivs:
             continue
         merged = _union(ivs)
@@ -132,7 +146,8 @@ def summarize(trace: dict) -> Summary:
                 idle[name] = idle.get(name, 0.0) + sec
     n_chips = max(len(busy), 1)
     return Summary((hi - lo) * 1e-9, sum(busy) / n_chips, op_s, op_n,
-                   {k: v / n_chips for k, v in idle.items()})
+                   {k: v / n_chips for k, v in idle.items()}, lanes,
+                   n_chips)
 
 
 def _gaps(merged: list, lo: float, hi: float) -> list:
