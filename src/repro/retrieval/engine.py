@@ -77,8 +77,8 @@ from repro.retrieval.store import (ROUTING_KEYS, VALIDITY_KEY,
                                    routing_arrays, scan_arrays)
 from repro.retrieval.topk import (allgather_topk, gathered_merge_topk,
                                   merge_topk)
-from repro.retrieval.tracing import (SCOPE_MASK, SCOPE_RERANK, SCOPE_SCAN,
-                                     record_trace)
+from repro.retrieval.tracing import (SCOPE_EXCHANGE, SCOPE_MASK,
+                                     SCOPE_RERANK, SCOPE_SCAN, record_trace)
 
 NEG = -1e30
 INT8_REF_CHUNK = 1024      # fallback scan chunk for int8 stores in ref mode
@@ -503,16 +503,18 @@ def _build_body(mesh: Mesh | None, stages: tuple, capacities: tuple,
                         # the dead-filler sentinel end-to-end (Retriever
                         # translates it to page id -1; a later stage scores it
                         # NEG in every segment since it is in-segment nowhere).
-                        parts_v.append(jax.lax.all_gather(s, axes, axis=1,
-                                                          tiled=True))
                         gi = jnp.where(ok, jnp.take_along_axis(cand, order,
                                                                axis=1), -1)
-                        parts_i.append(jax.lax.all_gather(gi, axes, axis=1,
-                                                          tiled=True))
-                    scores, cand = merge_topk(
-                        jnp.concatenate(parts_v, axis=1),
-                        jnp.concatenate(parts_i, axis=1),
-                        min(stage.k, L))
+                        with jax.named_scope(SCOPE_EXCHANGE):
+                            parts_v.append(jax.lax.all_gather(
+                                s, axes, axis=1, tiled=True))
+                            parts_i.append(jax.lax.all_gather(
+                                gi, axes, axis=1, tiled=True))
+                    with jax.named_scope(SCOPE_EXCHANGE):
+                        scores, cand = merge_topk(
+                            jnp.concatenate(parts_v, axis=1),
+                            jnp.concatenate(parts_i, axis=1),
+                            min(stage.k, L))
         return scores, cand
 
     def searcher(stores, q, q_mask, fspec):

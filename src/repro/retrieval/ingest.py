@@ -19,6 +19,11 @@ headroom.
     the tenant-id and packed tag-bitset store companions, stamped from
     traced values (tenant churn never retraces)
 
+On a doc-sharded store (``SegmentedStore.mesh``) the same fused program
+runs under ``shard_map`` (``IngestPipeline.sharded_write``): the batch
+is replicated, every device indexes it with the pooling kernel at its
+own shapes, and writes only the rows its shard owns.
+
 Batch sizes are padded into power-of-two INGEST BUCKETS (symmetric with
 the bucketed segment capacities of PR 2 and the query-shape buckets of
 PR 3), and ``tracing.record_trace()`` sits in the traced body, so after
@@ -56,7 +61,7 @@ from repro.kernels import dispatch as DSP
 from repro.kernels.pooling import ops as POPS
 from repro.kernels.pooling.ops import pool_pages_fused
 from repro.retrieval import tracing
-from repro.retrieval.segments import bucket_capacity
+from repro.retrieval.segments import bucket_capacity, sharded_write
 from repro.retrieval.store import (FILTER_KEY, TENANT_KEY, VALIDITY_KEY,
                                    VectorStore, is_store_companion,
                                    mask_key, pack_tags, quantize_vectors)
@@ -139,6 +144,7 @@ class IngestPipeline:
         self._jit_index = jax.jit(
             lambda pages, tt, h: self._index_arrays(pages, tt, h))
         self._jit_write = jax.jit(self._write_body)
+        self._sharded_writes: dict = {}       # mesh -> sharded_write(mesh)
         self.produced_keys = tuple(sorted(jax.eval_shape(
             lambda p, t: self._index_arrays(p, t, None),
             jax.ShapeDtypeStruct((self.min_bucket, cfg.seq_len,
@@ -258,6 +264,35 @@ class IngestPipeline:
             vectors = quantize_vectors(vectors, self.quantize, self.stages)
         return vectors
 
+    def _blocks(self, pages, token_types, n_real, tenant,
+                filter_row) -> dict:
+        """Index the (bucket-padded) batch into the bucket-wide row block
+        the write lays down for every segment array, store companions
+        included. The rows beyond ``n_real`` are padding: their content is
+        the allocation state (zeros, invalid, tenant 0, no tags)."""
+        batch = self._index_arrays(pages, token_types, None)
+        bucket = pages.shape[0]
+        row_valid = jnp.arange(bucket) < n_real
+        # zero the padding rows' derived content (pooled masks and
+        # quantisation scales are nonzero even for zero pages), so a
+        # never-claimed slot holds exactly its allocation state and
+        # segment arrays stay bitwise-identical to the legacy
+        # build_store + add_pages path
+        out = {k: jnp.where(
+            row_valid.reshape((bucket,) + (1,) * (v.ndim - 1)),
+            v, jnp.zeros_like(v)) for k, v in batch.items()}
+        out[VALIDITY_KEY] = row_valid
+        # the batch's tenant id and packed tag bitset are traced VALUES
+        # stamped onto the claimed rows — different tenants/tags reuse
+        # the executable
+        out[TENANT_KEY] = jnp.where(row_valid, tenant, jnp.int32(0))
+        out[FILTER_KEY] = jnp.where(
+            row_valid[:, None],
+            jnp.broadcast_to(filter_row[None, :],
+                             (bucket, filter_row.shape[0])),
+            jnp.uint32(0))
+        return out
+
     def _write_body(self, seg_vectors: dict, pages, token_types,
                     start, n_real, tenant, filter_row) -> dict:
         """Index the (bucket-padded) batch and write it into the segment's
@@ -269,36 +304,30 @@ class IngestPipeline:
         ``start + n_real``, overwriting them; ``reserve`` guarantees a
         full bucket of tail headroom so the DUS start clamp can never
         reach back into live rows."""
-        batch = self._index_arrays(pages, token_types, None)
-        bucket = pages.shape[0]
-        row_valid = jnp.arange(bucket) < n_real
         out = dict(seg_vectors)
-        for k, v in batch.items():
-            # zero the padding rows' derived content (pooled masks and
-            # quantisation scales are nonzero even for zero pages), so a
-            # never-claimed slot holds exactly its allocation state and
-            # segment arrays stay bitwise-identical to the legacy
-            # build_store + add_pages path
-            v = jnp.where(row_valid.reshape((bucket,) + (1,) * (v.ndim - 1)),
-                          v, jnp.zeros_like(v))
+        for k, v in self._blocks(pages, token_types, n_real, tenant,
+                                 filter_row).items():
             idx = (start,) + (0,) * (v.ndim - 1)
             out[k] = jax.lax.dynamic_update_slice(
                 seg_vectors[k], v.astype(seg_vectors[k].dtype), idx)
-        out[VALIDITY_KEY] = jax.lax.dynamic_update_slice(
-            seg_vectors[VALIDITY_KEY], row_valid, (start,))
-        # the batch's tenant id and packed tag bitset are traced VALUES
-        # stamped onto the claimed rows (zeros on padding, matching the
-        # allocation state) — different tenants/tags reuse this executable
-        out[TENANT_KEY] = jax.lax.dynamic_update_slice(
-            seg_vectors[TENANT_KEY],
-            jnp.where(row_valid, tenant, jnp.int32(0)), (start,))
-        frows = jnp.where(row_valid[:, None],
-                          jnp.broadcast_to(filter_row[None, :],
-                                           (bucket, filter_row.shape[0])),
-                          jnp.uint32(0))
-        out[FILTER_KEY] = jax.lax.dynamic_update_slice(
-            seg_vectors[FILTER_KEY], frows, (start, 0))
         return out
+
+    def sharded_write(self, mesh):
+        """The fused write over a doc-sharded segment on ``mesh``: jitted
+        ``fn(seg_vectors, start, pages, token_types, n_real, tenant,
+        filter_row)`` (``segments.sharded_write``) for the arrays the
+        batch writes. The whole program runs under ``shard_map``, so the
+        pooling kernel runs on each device as a kernel of that device's
+        shapes (the TPU compiler cannot partition a kernel itself). The
+        batch comes in replicated; every shard indexes it (``_blocks``)
+        and writes only the block rows that fall in its own slab, so a
+        block that straddles a shard boundary is split between its owners
+        and no shard gathers the segment. Made once per mesh."""
+        fn = self._sharded_writes.get(mesh)
+        if fn is None:
+            fn = self._sharded_writes[mesh] = sharded_write(mesh,
+                                                            self._blocks)
+        return fn
 
     # ------------------------------------------------------------------
     # host entry points
@@ -362,8 +391,18 @@ class IngestPipeline:
         seg_i, start = store.reserve(n, min_free=bucket)
         seg = store.segments[seg_i]
         words = pack_tags(tags, store.filter_words)
-        new_vectors = self._jit_write(seg.vectors, pages_p, tt,
-                                      jnp.int32(start), jnp.int32(n),
-                                      jnp.int32(int(tenant)),
-                                      jnp.asarray(words))
+        s32, rest = jnp.int32(start), (jnp.int32(n), jnp.int32(int(tenant)),
+                                       jnp.asarray(words))
+        if store.mesh is None:
+            new_vectors = self._jit_write(seg.vectors, pages_p, tt, s32,
+                                          *rest)
+        else:
+            # the arrays the batch writes, doc-sharded; the routing
+            # companions (replicated) pass through untouched
+            keys = (*self.produced_keys, VALIDITY_KEY, TENANT_KEY,
+                    FILTER_KEY)
+            new_vectors = dict(seg.vectors)
+            new_vectors.update(self.sharded_write(store.mesh)(
+                {k: seg.vectors[k] for k in keys}, s32, pages_p, tt,
+                *rest))
         return store.commit(seg_i, new_vectors, n)

@@ -52,6 +52,14 @@ already-indexed ``VectorStore`` batch into headroom (one
 ``repro.retrieval.ingest.IngestPipeline`` computes AND writes a raw batch
 in one fused jit, using the shared ``reserve``/``commit`` slot
 bookkeeping below.
+
+On a mesh the store is doc-sharded: shard s owns a contiguous
+``capacity / n_shards`` slots of every segment. Allocation makes each
+array in its sharding, every device writing only its own slab, and every
+block write runs under ``shard_map`` (``sharded_write``; the ingest
+pipeline's fused write does the same), each shard writing only the block
+rows that fall in its slab (``owned_rows``). A block may straddle shard
+boundaries; no device ever gathers a segment.
 """
 from __future__ import annotations
 
@@ -60,6 +68,7 @@ from dataclasses import dataclass
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.retrieval import routing as RT
@@ -96,6 +105,59 @@ def _write_block(arr: jax.Array, block: jax.Array, start) -> jax.Array:
     record_trace()
     idx = (start,) + (0,) * (arr.ndim - 1)
     return jax.lax.dynamic_update_slice(arr, block, idx)
+
+
+def owned_rows(slab: jax.Array, block: jax.Array, local_start) -> jax.Array:
+    """Write into one shard's ``slab`` the rows of ``block`` it owns:
+    block row j lands at slab row ``local_start + j`` when that row lies
+    in the slab, and is dropped otherwise. ``local_start`` is the block's
+    global start slot minus the shard's first slot, so it may be negative
+    or past the slab's end: a block that straddles a shard boundary is
+    split between the two owners, and a shard the block misses is left
+    as it was. One ``dynamic_update_slice`` of at most ``len(block)``
+    rows: the shard reads its rows at the clamped window, keeps those the
+    block does not cover, and writes the window back."""
+    n_local, bucket = slab.shape[0], block.shape[0]
+    w = min(bucket, n_local)
+    pos = jnp.clip(local_start, 0, n_local - w)
+    # block row for each window row, through a block padded by w rows on
+    # both sides so the slice is in bounds however far off the block is
+    shift = pos - local_start
+    pad = ((w, w),) + ((0, 0),) * (block.ndim - 1)
+    rows = jax.lax.dynamic_slice_in_dim(jnp.pad(block, pad), shift + w, w)
+    j = shift + jnp.arange(w)
+    take = ((j >= 0) & (j < bucket)).reshape((w,) + (1,) * (block.ndim - 1))
+    window = jax.lax.dynamic_slice_in_dim(slab, pos, w)
+    return jax.lax.dynamic_update_slice_in_dim(
+        slab, jnp.where(take, rows.astype(slab.dtype), window), pos, 0)
+
+
+def sharded_write(mesh, blocks=lambda b: b):
+    """The doc-sharded twin of ``_write_block`` over ``mesh``: jitted
+    ``fn(arrays, start, *args) -> arrays`` for a dict of segment arrays
+    (doc-sharded). Every shard computes the row blocks ``blocks(*args)``
+    from the replicated ``args`` (by default ``args`` is the dict of
+    blocks itself), a dict under the keys of ``arrays``, and writes only
+    its own rows (``owned_rows``), so no shard gathers the segment. Build
+    it once per mesh and keep it: a new function traces anew."""
+    axes = tuple(mesh.axis_names)
+
+    def body(arrays, start, *args):
+        record_trace()
+        shard = jax.lax.axis_index(axes)
+        rows = blocks(*args)
+        return {k: owned_rows(a, rows[k], start - shard * a.shape[0])
+                for k, a in arrays.items()}
+
+    @jax.jit
+    def write(arrays, start, *args):
+        specs = {k: P(axes) for k in arrays}
+        return shard_map(body, mesh=mesh,
+                         in_specs=(specs,) + (P(),) * (1 + len(args)),
+                         out_specs=specs, check_vma=False)(
+            arrays, start, *args)
+
+    return write
 
 
 @jax.jit
@@ -163,6 +225,7 @@ class SegmentedStore:
         # (two new companion arrays), so compiled search fns rebuild once.
         self.router = None
         self._slot_ids: np.ndarray | None = None   # slot->page-id cache
+        self._writer = None      # (mesh, sharded_write(mesh)), made once
         # bumped on every content mutation (upsert/delete/compact) so
         # result caches keyed on it can never serve pre-mutation answers
         self.generation = 0
@@ -190,26 +253,19 @@ class SegmentedStore:
         cap = _round_up(cap, n_shards)
         out = cls([], store.store_dtype, n_shards, next_id=0, mesh=mesh,
                   filter_words=filter_words)
-        out._alloc_segment(store.vectors, cap)
-        seg = out.segments[0]
+        seg = out._alloc_segment(store.vectors, cap)
         n = store.n_docs
-        for k, v in store.vectors.items():
-            seg.vectors[k] = _write_block(seg.vectors[k],
-                                          v.astype(seg.vectors[k].dtype),
-                                          jnp.int32(0))
-        seg.vectors[VALIDITY_KEY] = _write_block(
-            seg.vectors[VALIDITY_KEY], jnp.ones((n,), bool), jnp.int32(0))
+        blocks = {k: v.astype(seg.vectors[k].dtype)
+                  for k, v in store.vectors.items()}
+        blocks[VALIDITY_KEY] = jnp.ones((n,), bool)
         # stamp tenant 0 / no tags through the same write primitive an
         # ``add_pages`` of this batch shape uses — zeros over zeros, but it
         # warms those executables so a wrap-then-upsert serving loop stays
         # zero-retrace at the seed batch size (same contract as the data
         # arrays above)
-        seg.vectors[TENANT_KEY] = _write_block(
-            seg.vectors[TENANT_KEY], jnp.zeros((n,), jnp.int32),
-            jnp.int32(0))
-        seg.vectors[FILTER_KEY] = _write_block(
-            seg.vectors[FILTER_KEY],
-            jnp.zeros((n, out.filter_words), jnp.uint32), jnp.int32(0))
+        blocks[TENANT_KEY] = jnp.zeros((n,), jnp.int32)
+        blocks[FILTER_KEY] = jnp.zeros((n, out.filter_words), jnp.uint32)
+        out._write_rows(seg, blocks, 0)
         seg.doc_ids[:n] = np.arange(n)
         seg.n_docs = n
         out.next_id = n
@@ -228,11 +284,40 @@ class SegmentedStore:
                     else self._place(v))
                 for k, v in seg.vectors.items()}
 
+    def _doc_sharding(self) -> NamedSharding:
+        return NamedSharding(self.mesh, P(tuple(self.mesh.axis_names)))
+
     def _place(self, arr: jax.Array) -> jax.Array:
         if self.mesh is None:
             return arr
-        spec = P(tuple(self.mesh.axis_names))
-        return jax.device_put(arr, NamedSharding(self.mesh, spec))
+        return jax.device_put(arr, self._doc_sharding())
+
+    def _zeros(self, shape: tuple, dtype) -> jax.Array:
+        """A zero segment array, made in place: doc-sharded on a mesh, by
+        a program whose output sharding makes each device write only its
+        own slab (no device ever holds more)."""
+        if self.mesh is None:
+            return jnp.zeros(shape, dtype)
+
+        def zeros():
+            record_trace()
+            return jnp.zeros(shape, dtype)
+
+        return jax.jit(zeros, out_shardings=self._doc_sharding())()
+
+    def _write_rows(self, seg: Segment, blocks: dict, start: int) -> None:
+        """Write each block of ``blocks`` into ``seg``'s array of the same
+        key at slot ``start`` (``_write_block`` per array, or, on a mesh,
+        one ``sharded_write`` in which each shard writes only its rows)."""
+        s32 = jnp.int32(start)
+        if self.mesh is None:
+            for k, b in blocks.items():
+                seg.vectors[k] = _write_block(seg.vectors[k], b, s32)
+            return
+        if self._writer is None or self._writer[0] is not self.mesh:
+            self._writer = (self.mesh, sharded_write(self.mesh))
+        seg.vectors.update(self._writer[1](
+            {k: seg.vectors[k] for k in blocks}, s32, blocks))
 
     def _place_replicated(self, arr: jax.Array) -> jax.Array:
         if self.mesh is None:
@@ -244,14 +329,13 @@ class SegmentedStore:
         for k, v in like_vectors.items():
             if is_store_companion(k):
                 continue
-            vecs[k] = self._place(jnp.zeros((capacity,) + v.shape[1:],
-                                            v.dtype))
+            vecs[k] = self._zeros((capacity,) + v.shape[1:], v.dtype)
         # the store companions are always present and zero-initialised:
         # dead slots are invalid, tenant 0, no tags
-        vecs[VALIDITY_KEY] = self._place(jnp.zeros((capacity,), bool))
-        vecs[TENANT_KEY] = self._place(jnp.zeros((capacity,), jnp.int32))
-        vecs[FILTER_KEY] = self._place(
-            jnp.zeros((capacity, self.filter_words), jnp.uint32))
+        vecs[VALIDITY_KEY] = self._zeros((capacity,), bool)
+        vecs[TENANT_KEY] = self._zeros((capacity,), jnp.int32)
+        vecs[FILTER_KEY] = self._zeros((capacity, self.filter_words),
+                                       jnp.uint32)
         seg = Segment(vecs, capacity, 0, np.full((capacity,), -1, np.int64))
         if self.router is not None:
             arrays, state = RT.alloc_arrays(self.router, like_vectors,
@@ -349,21 +433,14 @@ class SegmentedStore:
                     f"vectors {sorted(names)}")
         seg_i, start = self.reserve(n, like=batch.vectors)
         seg = self.segments[seg_i]
-        s32 = jnp.int32(start)
-        for k, v in batch.vectors.items():
-            seg.vectors[k] = _write_block(
-                seg.vectors[k], jnp.asarray(v).astype(seg.vectors[k].dtype),
-                s32)
-        seg.vectors[VALIDITY_KEY] = _write_block(
-            seg.vectors[VALIDITY_KEY], jnp.ones((n,), bool), s32)
-        seg.vectors[TENANT_KEY] = _write_block(
-            seg.vectors[TENANT_KEY],
-            jnp.full((n,), int(tenant), jnp.int32), s32)
+        blocks = {k: jnp.asarray(v).astype(seg.vectors[k].dtype)
+                  for k, v in batch.vectors.items()}
+        blocks[VALIDITY_KEY] = jnp.ones((n,), bool)
+        blocks[TENANT_KEY] = jnp.full((n,), int(tenant), jnp.int32)
         words = pack_tags(tags, self.filter_words)
-        seg.vectors[FILTER_KEY] = _write_block(
-            seg.vectors[FILTER_KEY],
-            jnp.broadcast_to(jnp.asarray(words)[None, :],
-                             (n, self.filter_words)), s32)
+        blocks[FILTER_KEY] = jnp.broadcast_to(jnp.asarray(words)[None, :],
+                                              (n, self.filter_words))
+        self._write_rows(seg, blocks, start)
         return self.commit(seg_i, seg.vectors, n)
 
     def delete(self, ids) -> int:
@@ -424,13 +501,13 @@ class SegmentedStore:
         self.segments = []
         seg = self._alloc_segment(like, cap)
         if total:
-            s32 = jnp.int32(0)
+            # one array at a time, so one concatenated block is alive
             for k in names:
                 block = jnp.concatenate(rows[k], axis=0)
-                seg.vectors[k] = _write_block(
-                    seg.vectors[k], block.astype(seg.vectors[k].dtype), s32)
-            seg.vectors[VALIDITY_KEY] = _write_block(
-                seg.vectors[VALIDITY_KEY], jnp.ones((total,), bool), s32)
+                self._write_rows(
+                    seg, {k: block.astype(seg.vectors[k].dtype)}, 0)
+            self._write_rows(seg, {VALIDITY_KEY: jnp.ones((total,), bool)},
+                             0)
             seg.doc_ids[:total] = np.concatenate(ids)
         seg.n_docs = total
         if self.router is not None:

@@ -4,6 +4,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.retrieval.tracing import SCOPE_EXCHANGE
+
 NEG = -1e30
 
 
@@ -28,10 +30,11 @@ def gathered_merge_topk(vals: jax.Array, global_ids: jax.Array, k: int,
     Communication: S * B * k' * 8 bytes (scores + ids), never the
     documents. The merge half of ``allgather_topk``, reused directly by
     the streamed scan top-k path (whose local select already happened
-    chunk-by-chunk inside the scan)."""
-    av = jax.lax.all_gather(vals, axis_name, axis=1, tiled=True)  # [B,S*k']
-    ai = jax.lax.all_gather(global_ids, axis_name, axis=1, tiled=True)
-    return merge_topk(av, ai, k)
+    chunk-by-chunk inside the scan). Its ops carry the exchange scope."""
+    with jax.named_scope(SCOPE_EXCHANGE):
+        av = jax.lax.all_gather(vals, axis_name, axis=1, tiled=True)
+        ai = jax.lax.all_gather(global_ids, axis_name, axis=1, tiled=True)
+        return merge_topk(av, ai, k)              # over [B, S*k']
 
 
 def allgather_topk(scores_local: jax.Array, k: int, axis_name,

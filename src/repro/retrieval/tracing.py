@@ -65,8 +65,12 @@ constants below, defined here once:
 Device ops carry the cascade stage that emitted them as a
 ``jax.named_scope`` in their op metadata (``cascade.mask`` for the
 effective-validity masks, ``cascade.scan`` for stage 0 with its merge,
-``cascade.rerank`` for every later stage with its top-k and take). A
-scope changes op metadata only: no computation, fusion or kernel name.
+``cascade.rerank`` for every later stage with its top-k and take). On a
+doc-sharded store the cross-chip exchange of a stage, the all-gathers
+of each shard's (score, id) lists and the merge of what they gather,
+also carries ``cascade.exchange`` (``SCOPE_EXCHANGE``), nested inside
+its stage's scope. A scope changes op metadata only: no computation,
+fusion or kernel name.
 
 With no profiler running a span costs one inactive ``TraceMe``; no
 string is built on the hot path.
@@ -92,6 +96,7 @@ TRANSLATE = "frontend.translate"
 SCOPE_MASK = "cascade.mask"
 SCOPE_SCAN = "cascade.scan"
 SCOPE_RERANK = "cascade.rerank"
+SCOPE_EXCHANGE = "cascade.exchange"
 
 
 def record_trace(name: str | None = None) -> None:
